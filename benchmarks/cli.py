@@ -23,6 +23,7 @@ from collections.abc import Callable, Sequence
 from dataclasses import dataclass
 
 from benchmarks.check_regression import compare
+from repro.compile_cache import enable_compile_cache
 
 
 @dataclass(frozen=True)
@@ -68,7 +69,7 @@ def build_parser(
         default=0,
         metavar="N",
         help="shard the benchmark's fleet axis across a device mesh of "
-        "up to N local devices (-1 = all; 0 = single-device); ignored by "
+        "N local devices (a power of two; -1 = all; 0 = single-device); ignored by "
         "benchmarks without a mesh mode. On CPU combine with "
         "XLA_FLAGS=--xla_force_host_platform_device_count=N",
     )
@@ -142,6 +143,7 @@ def bench_main(
     args = build_parser(f"python -m benchmarks.{benchmark}", seed=seed).parse_args(
         argv
     )
+    enable_compile_cache()
     kwargs = dict(fast=args.fast, json_path=args.json)
     if seed:
         kwargs["seed"] = args.seed

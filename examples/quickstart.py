@@ -106,7 +106,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 q = jnp.asarray(rng.standard_normal((1, 128, 4, 32)), jnp.float32)
 k = jnp.asarray(rng.standard_normal((1, 128, 2, 32)), jnp.float32)
 v = jnp.asarray(rng.standard_normal((1, 128, 2, 32)), jnp.float32)
-out = flash_attention(q, k, v, block_q=64, block_k=64)
+out = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
 err = float(jnp.abs(out - attention_ref(q, k, v)).max())
 print(f"[kernels] flash attention (interpret) max err vs oracle: {err:.2e}")
 print("done.")

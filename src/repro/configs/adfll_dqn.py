@@ -58,9 +58,11 @@ class ADFLLConfig:
     # baseline; within float-fusion ULPs of the fused engine).
     engine: str = "fleet"
     # devices joining the fleet mesh (the stacked agent axis is sharded
-    # across them): 0 = single-device (no mesh), -1 = every local device,
-    # N = up to N — rounded down to a power of two; per-slot math is
-    # bitwise invariant to the mesh, so reports match the 0 setting.
+    # across them): 0 = single-device (no mesh), -1 = every local device
+    # (rounded down to a power of two), N = exactly N, a power of two
+    # (fewer visible devices is an error). On CPU per-slot math is
+    # bitwise invariant to the mesh, so reports match the 0 setting; on
+    # TPU they match only to float rounding (docs/scaling.md).
     fleet_devices: int = 0
     # task curriculum: "roundrobin" (the paper's rotation), "blocked"
     # (one task per cohort of n_agents draws before advancing), or

@@ -119,16 +119,17 @@ def erb_take(
     erb: ERB, idx: np.ndarray, *, use_pallas: bool = False
 ) -> dict[str, np.ndarray]:
     """Materialize the rows selected by ``idx`` (host gather, or the
-    Pallas ``replay_gather`` kernel when ``use_pallas``)."""
+    compiled Pallas ``replay_gather`` kernel when ``use_pallas`` — a TPU
+    path; there is no Pallas fallback on other backends)."""
     n = len(idx)
     if use_pallas:
         from repro.kernels.replay_gather.ops import replay_gather
 
         flat = {}
         for k, v in erb.data.items():
-            arr = jnp.asarray(v).reshape(erb.capacity, -1)
+            arr = jnp.asarray(v).reshape(erb.capacity, 1, -1)
             w = jnp.ones((n,), jnp.float32)
-            out = replay_gather(arr, jnp.asarray(idx, jnp.int32), w)
+            out = replay_gather(arr, jnp.asarray(idx, jnp.int32), w, mode="compiled")
             flat[k] = np.asarray(out).reshape((n,) + v.shape[1:])
         return flat
     return {k: v[idx] for k, v in erb.data.items()}
@@ -161,15 +162,19 @@ def flat_width(obs_shape: tuple[int, ...]) -> int:
     return 2 * obs_f + 3 + 3 + 3  # obs+next_obs, loc+next_loc, a/r/done
 
 
-def erb_flatten(erb: ERB) -> np.ndarray:
-    """[size, F] float32 view of the filled rows, columns in FLAT_FIELDS
-    order (action stored as float32 — exact for small ints)."""
+def erb_flatten(erb: ERB, width: int | None = None) -> np.ndarray:
+    """[size, width] float32 copy of the filled rows, columns in
+    FLAT_FIELDS order (action stored as float32 — exact for small ints),
+    zero-padded on the right from F to ``width`` (default: F)."""
     s = erb.size
-    cols = []
-    for k in FLAT_FIELDS:
-        v = erb.data[k][:s]
-        cols.append(v.reshape(s, -1).astype(np.float32, copy=False))
-    return np.concatenate(cols, axis=1)
+    cols = [erb.data[k][:s].reshape(s, -1) for k in FLAT_FIELDS]
+    feat = sum(c.shape[1] for c in cols)
+    out = np.zeros((s, feat if width is None else width), np.float32)
+    o = 0
+    for c in cols:
+        out[:, o : o + c.shape[1]] = c
+        o += c.shape[1]
+    return out
 
 
 def erb_share_slice(

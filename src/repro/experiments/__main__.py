@@ -60,10 +60,10 @@ def main(argv=None) -> int:
         default=None,
         metavar="N",
         help=(
-            "shard the fleet's agent axis across a device mesh of up to N "
-            "local devices (-1 = all; rounded down to a power of two). "
-            "Per-slot math is bitwise invariant to the mesh, so reports "
-            "match single-device runs. On CPU combine with "
+            "shard the fleet's agent axis across a device mesh of N local "
+            "devices (a power of two; -1 = all, rounded down to one). "
+            "On CPU per-slot math is bitwise invariant to the mesh, so "
+            "reports match single-device runs; combine with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N."
         ),
     )
@@ -149,4 +149,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

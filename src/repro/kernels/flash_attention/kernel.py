@@ -114,7 +114,7 @@ def flash_attention_bhsd(
     softcap: float = 0.0,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q [BH, S, D], k/v [BH_kv, S, D*] (BH = BH_kv * group). -> [BH, S, Dv]."""
     bh, s, d = q.shape

@@ -34,7 +34,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """q [B,S,Hq,D], k/v [B,S,Hkv,D*] -> [B,S,Hq,Dv]."""
     b, s, hq, d = q.shape

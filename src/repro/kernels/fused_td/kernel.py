@@ -43,7 +43,7 @@ def fused_td(
     *,
     gamma: float,
     block_b: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """q_sel [B,1], q_next [B,A], reward [B,1], done [B,1] ->
     (loss [B,1], dq [B,1])."""
@@ -70,4 +70,5 @@ def fused_td(
             jax.ShapeDtypeStruct((b, 1), F32),
         ],
         interpret=interpret,
+        name="fused_td",
     )(q_sel.astype(F32), q_next.astype(F32), reward.astype(F32), done.astype(F32))

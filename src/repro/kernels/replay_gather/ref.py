@@ -4,4 +4,4 @@ from __future__ import annotations
 
 
 def replay_gather_ref(buffer, indices, weights):
-    return buffer[indices] * weights.astype(buffer.dtype)[:, None]
+    return buffer[indices] * weights.astype(buffer.dtype)[:, None, None]

@@ -25,7 +25,7 @@ def _kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(
-    x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret: bool = True
+    x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret: bool = False
 ):
     """x [N, d], scale [d] -> [N, d]."""
     n, d = x.shape

@@ -11,7 +11,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm as _kernel
 
 @partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(
-    x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret: bool = True
+    x, scale, *, eps: float = 1e-5, block_rows: int = 128, interpret: bool = False
 ):
     lead = x.shape[:-1]
     y = _kernel(

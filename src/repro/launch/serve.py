@@ -207,4 +207,7 @@ def _load_prefill(cfg, caches, pre_caches, s):
 if __name__ == "__main__":
     import sys
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

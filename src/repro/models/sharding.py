@@ -259,19 +259,27 @@ def cache_shardings(tree, mesh: Mesh, policy: ShardingPolicy):
 def make_fleet_mesh(n_devices: int | None = None, *, axis: str = FLEET) -> Mesh | None:
     """A 1-D device mesh for the fleet's stacked agent axis.
 
-    ``n_devices`` caps how many local devices join (``None``/``-1`` = all
-    of them); the count is rounded *down* to a power of two so the
-    engine's pow2 slot buckets always divide the mesh. Returns ``None``
-    when at most one device would participate — callers treat that as
-    "stay on the single-device path", so ``make_fleet_mesh()`` is safe to
-    call unconditionally on CPU CI.
+    ``n_devices`` is how many local devices join. ``None``/``-1`` means
+    all of them, rounded *down* to a power of two so the engine's pow2
+    slot buckets always divide the mesh. An explicit count must be a
+    power of two that the visible devices cover; anything else raises —
+    a mesh never comes out smaller than asked. Returns ``None`` when one
+    device would participate — callers treat that as "stay on the
+    single-device path", so ``make_fleet_mesh()`` is safe to call
+    unconditionally on CPU CI.
     """
     devices = jax.devices()
-    n = len(devices) if n_devices is None or n_devices < 0 else n_devices
-    n = min(n, len(devices))
+    if n_devices is None or n_devices < 0:
+        n = 1 << (len(devices).bit_length() - 1)  # pow2 floor
+    else:
+        n = n_devices
+        if n & (n - 1) or n > len(devices):
+            raise ValueError(
+                f"a fleet mesh of {n} devices needs a power of two no larger "
+                f"than the {len(devices)} visible device(s)"
+            )
     if n <= 1:
         return None
-    n = 1 << (n.bit_length() - 1)  # pow2 floor
     return Mesh(np.array(devices[:n]), (axis,))
 
 

@@ -17,12 +17,12 @@ steps = N*K dispatches per round of rounds. This module turns that into
   donated on accelerators (donation is a no-op on CPU).
 * Fleet-axis sharding: given a 1-D device mesh
   (:func:`repro.models.sharding.make_fleet_mesh`), the stacked agent
-  axis is partitioned across devices (MaxText-style ``jax.sharding``
-  annotations: state and indices sharded on the agent axis, replay pool
-  replicated) and the chunk is jitted with explicit in/out shardings —
-  per-agent work is embarrassingly parallel, so the compiler places each
-  shard's slots on its device with no cross-slot collectives and
-  throughput scales with the device count. The engine pads its resident
+  axis is partitioned across devices (state and indices sharded on the
+  agent axis, replay pool replicated) and the chunk is one ``shard_map``
+  program over that axis — per-agent work is embarrassingly parallel, so
+  each device trains its own slots with no collective at all (left to
+  the automatic partitioner, the TPU compiler splits the vmapped convs
+  across devices and inserts all-gathers). The engine pads its resident
   slot count to a mesh-divisible pow2 bucket (dead slots are inert
   copies, never read), and a flush that covers the whole bucket skips
   the gather/scatter entirely: the resident state flows through the
@@ -34,11 +34,14 @@ steps = N*K dispatches per round of rounds. This module turns that into
   round-trips. Each lane applies its agent's params to its own ``[B]``
   batch — the identical slot program — so stacked collection is
   bit-identical to per-agent acting.
-* Device-resident replay: ERBs are cached on device as flat ``[size, F]``
-  float32 matrices; the host :class:`~repro.core.replay.SelectiveReplaySampler`
+* Device-resident replay: ERBs are cached on device as ``[size, 1, W]``
+  float32 row views (``W`` = the flat row width padded to whole TPU
+  lanes, see ``kernels/replay_gather/ops.py``); the host
+  :class:`~repro.core.replay.SelectiveReplaySampler`
   shrinks to pool/index *selection* (its ``plan()`` half), and batch
-  materialization happens inside the compiled chunk through the
-  ``replay_gather`` Pallas kernel — one stacked host->device index
+  materialization happens inside the compiled chunk through
+  ``replay_gather`` (the compiled Pallas kernel on TPU, XLA's gather
+  elsewhere: :attr:`FleetSteps.gather_mode`) — one stacked host->device index
   transfer per scan chunk instead of one batch transfer per step.
 * :class:`FleetEngine` — the host-side orchestrator: slots, lazy job
   queue, flush-on-read semantics. ``DQNAgent`` is a thin view over a
@@ -60,6 +63,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from collections.abc import Callable, Sequence
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
 import jax
@@ -69,7 +73,7 @@ import numpy as np
 from repro.configs.adfll_dqn import DQNConfig
 from repro.core.erb import ERB, erb_add, erb_flatten, flat_width
 from repro.kernels.fused_td.ops import td_loss
-from repro.kernels.replay_gather.ops import replay_gather
+from repro.kernels.replay_gather.ops import pool_width, replay_gather, resolve_mode
 from repro.models.sharding import FleetSharding
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.rl.dqn import dqn_apply, dqn_init
@@ -134,7 +138,7 @@ class FleetSteps:
 
     ``train_chunk(state_slice, pool, idx) -> (state_slice, losses)`` where
     ``state_slice`` is a :class:`FleetState` of the participating slots,
-    ``pool`` is the flat ``[R, F]`` device replay pool shared by the
+    ``pool`` is the ``[R, 1, W]`` device replay pool shared by the
     chunk, and ``idx`` is the ``[K, N, B]`` int32 global row-index tensor
     (the one host->device transfer of a flush). ``n_traces`` counts
     retraces — the no-recompilation tests assert it stays at 1 across
@@ -148,14 +152,18 @@ class FleetSteps:
     enabling the observatory adds no extra host syncs.  It is compiled
     lazily on first use: engines without an observatory never trace it.
 
-    With a ``mesh`` (1-D agent-axis device mesh), both chunks are jitted
-    with explicit in/out shardings: state leaves and the ``[K, N, B]``
-    index tensor partitioned on the agent axis, the replay pool
-    replicated. The slot program has no cross-slot data flow, so the
-    compiler runs each device's shard independently — agents-per-device
-    throughput scaling with bitwise-identical per-slot math (the same
+    With a ``mesh`` (1-D agent-axis device mesh), both chunks are
+    ``shard_map`` programs jitted with explicit in/out shardings: state
+    leaves and the ``[K, N, B]`` index tensor partitioned on the agent
+    axis, the replay pool replicated. Each device runs the slot program
+    on its own shard — agents-per-device throughput scaling. On CPU the
+    per-slot math is bitwise identical to a single-device run (the same
     N-invariance that backs the fleet-vs-sequential guarantee; asserted
-    against a single-device run in ``tests/test_fleet.py``).
+    in ``tests/test_fleet.py``); on TPU the compiled program depends on
+    the lane count, so the mesh matches one device only shard by shard.
+
+    ``gather_mode`` names the replay gather the chunks run
+    (:func:`repro.kernels.replay_gather.ops.resolve_mode` of ``"auto"``).
     """
 
     def __init__(self, cfg: DQNConfig, use_pallas: bool, mesh=None):
@@ -165,12 +173,16 @@ class FleetSteps:
         self.sharding = FleetSharding(mesh) if mesh is not None else None
         self.opt_cfg = make_dqn_opt_cfg(cfg)
         self.n_traces = 0
+        # the replay gather this program runs: the backend's named
+        # choice (compiled Pallas kernel on TPU, XLA gather elsewhere)
+        self.gather_mode = resolve_mode("auto")
         box = cfg.box_size
         obs_f = box[0] * box[1] * box[2]
-        feat = flat_width(box)
+        feat = pool_width(flat_width(box))
 
         def split_rows(rows):
-            """[B, F] flat rows -> batch dict (FLAT_FIELDS column order)."""
+            """[B, feat] pool rows -> batch dict (FLAT_FIELDS column
+            order; the zero lanes past the last field are ignored)."""
             b = rows.shape[0]
             o = 0
             out = {}
@@ -193,6 +205,17 @@ class FleetSteps:
                     v = v.astype(jnp.int32)
                 out[key] = v
             return out
+
+        def gather_batch(pool, idx_k):
+            """[R, 1, feat] pool, [N, B] row indices -> stacked batch dict."""
+            n, b = idx_k.shape
+            rows = replay_gather(
+                pool,
+                idx_k.reshape(-1),
+                jnp.ones((n * b,), jnp.float32),
+                mode=self.gather_mode,
+            )
+            return jax.vmap(split_rows)(rows.reshape(n, b, feat))
 
         loss_fn = make_dqn_loss_fn(cfg, use_pallas)
 
@@ -249,14 +272,7 @@ class FleetSteps:
 
             def body(carry, idx_k):
                 p, t, o, c = carry
-                n, b = idx_k.shape
-                rows = replay_gather(
-                    pool,
-                    idx_k.reshape(-1),
-                    jnp.ones((n * b,), jnp.float32),
-                    mode="auto",  # compiled kernel on TPU, XLA gather on CPU
-                )
-                batch = jax.vmap(split_rows)(rows.reshape(n, b, feat))
+                batch = gather_batch(pool, idx_k)
                 p, t, o, c, loss = jax.vmap(slot_step)(p, t, o, c, batch)
                 return (p, t, o, c), loss
 
@@ -270,14 +286,7 @@ class FleetSteps:
 
             def body(carry, idx_k):
                 p, t, o, c = carry
-                n, b = idx_k.shape
-                rows = replay_gather(
-                    pool,
-                    idx_k.reshape(-1),
-                    jnp.ones((n * b,), jnp.float32),
-                    mode="auto",
-                )
-                batch = jax.vmap(split_rows)(rows.reshape(n, b, feat))
+                batch = gather_batch(pool, idx_k)
                 p, t, o, c, loss, td, qm, gn = jax.vmap(slot_step_stats)(
                     p, t, o, c, batch
                 )
@@ -302,45 +311,60 @@ class FleetSteps:
 
         # donated stacked buffers: in-place update on accelerators
         # (donation is unimplemented on CPU; avoid the warning spam there)
-        donate = () if jax.default_backend() == "cpu" else (0,)
-        if self.sharding is None:
-            self.train_chunk: Callable = jax.jit(chunk, donate_argnums=donate)
-        else:
-            fs = self.sharding
-            self.train_chunk = jax.jit(
-                chunk,
-                donate_argnums=donate,
-                in_shardings=(fs.stacked, fs.replicated, fs.indices),
-                out_shardings=(fs.stacked, fs.indices),
-            )
+        self._donate = () if jax.default_backend() == "cpu" else (0,)
+        self.train_chunk: Callable = self._jit(chunk, "indices")
         self._chunk_stats_fn = chunk_stats
-        self._donate = donate
         self._train_chunk_stats: Callable | None = None
+
+    def _jit(self, chunk: Callable, out_kind) -> Callable:
+        """Jit a chunk. On a mesh the chunk is one ``shard_map`` program
+        over the fleet axis: each device runs the slot program on its own
+        agents against its replica of the pool, so the compiler partitions
+        nothing — no collective is inserted, and Pallas kernels (which
+        cannot be partitioned automatically) stay legal inside.
+        ``out_kind`` names the sharding of the chunk's second output, a
+        :class:`FleetSharding` property or a dict of them."""
+        if self.sharding is None:
+            return jax.jit(chunk, donate_argnums=self._donate)
+        fs = self.sharding
+        in_sh = (fs.stacked, fs.replicated, fs.indices)
+        out_sh = (fs.stacked, jax.tree_util.tree_map(partial(getattr, fs), out_kind))
+
+        def specs(tree):
+            return jax.tree_util.tree_map(lambda sh: sh.spec, tree)
+
+        # check_vma=False: the Pallas kernels' output shapes carry no
+        # varying-axis annotation, and a program with no collective has
+        # nothing for that checker to check
+        body = jax.shard_map(
+            chunk,
+            mesh=self.mesh,
+            in_specs=specs(in_sh),
+            out_specs=specs(out_sh),
+            check_vma=False,
+        )
+        return jax.jit(
+            body,
+            donate_argnums=self._donate,
+            in_shardings=in_sh,
+            out_shardings=out_sh,
+        )
 
     @property
     def train_chunk_stats(self) -> Callable:
         """The stats-carrying chunk, jitted on first use (engines without
         an observatory never pay its trace/compile)."""
         if self._train_chunk_stats is None:
-            if self.sharding is None:
-                self._train_chunk_stats = jax.jit(
-                    self._chunk_stats_fn, donate_argnums=self._donate
-                )
-            else:
-                fs = self.sharding
-                stats_out = {
-                    "loss": fs.indices,  # [K, N]
-                    "td_abs": fs.indices,
-                    "q_max": fs.indices,
-                    "grad_norm": fs.indices,
-                    "params_finite": fs.stacked,  # [N]
-                }
-                self._train_chunk_stats = jax.jit(
-                    self._chunk_stats_fn,
-                    donate_argnums=self._donate,
-                    in_shardings=(fs.stacked, fs.replicated, fs.indices),
-                    out_shardings=(fs.stacked, stats_out),
-                )
+            self._train_chunk_stats = self._jit(
+                self._chunk_stats_fn,
+                {
+                    "loss": "indices",  # [K, N]
+                    "td_abs": "indices",
+                    "q_max": "indices",
+                    "grad_norm": "indices",
+                    "params_finite": "stacked",  # [N]
+                },
+            )
         return self._train_chunk_stats
 
     def init_slot(self, seed: int) -> FleetState:
@@ -393,7 +417,8 @@ class CollectSteps:
     host consuming each agent's own rng stream in the per-agent order.
 
     With a ``mesh``, all three operands are sharded on the leading agent
-    axis, so collection scales with devices like the train chunk.
+    axis and the program is a ``shard_map`` over it, so collection scales
+    with devices like the train chunk, with no collective.
     ``n_traces`` counts retraces — one compile per distinct ``(A, B)``
     bucket (cohorts pad the agent axis to pow2 buckets).
     """
@@ -412,9 +437,14 @@ class CollectSteps:
         if mesh is None:
             self.qvals: Callable = jax.jit(qvals)
         else:
+            # one shard_map program, like the train chunk: each device
+            # acts for its own agents, with no collective
             fs = FleetSharding(mesh)
+            spec = fs.stacked.spec
             self.qvals = jax.jit(
-                qvals,
+                jax.shard_map(
+                    qvals, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+                ),
                 in_shardings=(fs.stacked, fs.stacked, fs.stacked),
                 out_shardings=fs.stacked,
             )
@@ -645,7 +675,7 @@ class FleetEngine:
         self.erb_cache_size = erb_cache_size
         self.erb_cache_bytes = erb_cache_bytes
         self.pool_bucket_floor = pool_bucket_floor
-        self._feat = flat_width(cfg.box_size)
+        self._feat = pool_width(flat_width(cfg.box_size))
         self._pending: list[_Job] = []
         self._pending_slots: set = set()
         self._erb_cache: OrderedDict[tuple[str, int], jax.Array] = OrderedDict()
@@ -782,7 +812,8 @@ class FleetEngine:
 
     # -- replay pool ----------------------------------------------------------
     def _flat_erb(self, erb: ERB) -> jax.Array:
-        """Device-resident [size, F] matrix of an ERB (LRU-cached; keyed
+        """Device-resident ``[size, 1, pool_width(F)]`` rows of an ERB (the
+        gather kernel's row view, zero lanes on the right; LRU-cached; keyed
         by (erb_id, version) so host-side ring appends invalidate; bounded
         by entry count *and* total bytes — at paper-scale buffers the byte
         budget binds first)."""
@@ -793,7 +824,7 @@ class FleetEngine:
             self.telemetry.count("fleet.erb_cache.hits", 1)
             return hit
         self.telemetry.count("fleet.erb_cache.misses", 1)
-        flat = jnp.asarray(erb_flatten(erb))
+        flat = jnp.asarray(erb_flatten(erb, self._feat)[:, None, :])
         self._erb_cache[key] = flat
         self._erb_cache_nbytes += flat.nbytes
         while len(self._erb_cache) > 1 and (
@@ -875,7 +906,7 @@ class FleetEngine:
         # to bound the number of compiled (K, N, R) shape variants
         r_pad = max(self.pool_bucket_floor, _pow2(total))
         if r_pad > total:
-            parts.append(jnp.zeros((r_pad - total, self._feat), jnp.float32))
+            parts.append(jnp.zeros((r_pad - total, 1, self._feat), jnp.float32))
         pool = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
         n_min = self.mesh.size if self.mesh is not None else 1
         n_pad = max(_pow2(n_real), n_min)

@@ -6,7 +6,10 @@ cells), results stream back to the parent, and every finished cell is
 appended to the :class:`~repro.sweeps.store.ReportStore` as it lands —
 an interrupted sweep resumes from the store and re-executes only the
 missing cells.  ``workers=1`` (or ``0``) runs cells inline in this
-process, which is what tests and tiny grids want.
+process, which is what tests and tiny grids want.  The pool is used only
+when ``JAX_PLATFORMS=cpu``: on an accelerator host one process holds the
+chip, and a child that needs it fails or hangs, so there every sweep
+runs inline.
 
 Wall-time budgets are enforced per cell: an interval timer inside the
 worker interrupts a cell that overruns its budget (Python-level code;
@@ -44,6 +47,13 @@ from repro.sweeps.store import (
 
 def default_workers(n_cells: int) -> int:
     return max(1, min(4, os.cpu_count() or 1, n_cells))
+
+
+def pool_allowed() -> bool:
+    """Whether cells may run in child processes: only when JAX is held
+    to the CPU. Read from the environment, so deciding initialises no
+    backend in the parent."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
 
 
 class _BudgetExceeded(Exception):
@@ -163,6 +173,13 @@ def run_sweep(
         )
 
     n_workers = default_workers(len(pending)) if workers is None else workers
+    if pending and n_workers > 1 and not pool_allowed():
+        (echo or print)(
+            f"sweep {sweep.name}: running {len(pending)} cells inline, not in "
+            f"{n_workers} workers — JAX_PLATFORMS is not 'cpu', and only one "
+            "process can hold the accelerator"
+        )
+        n_workers = 1
     if pending and n_workers <= 1:
         for cell in pending:
             record(_run_cell((cell, budget)))
@@ -205,4 +222,4 @@ def failed_cells(summary: dict[str, Any]) -> list[dict[str, Any]]:
     return [c for c in summary.get("cells", []) if c.get("status") != STATUS_OK]
 
 
-__all__ = ["default_workers", "failed_cells", "run_sweep"]
+__all__ = ["default_workers", "failed_cells", "pool_allowed", "run_sweep"]

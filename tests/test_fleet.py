@@ -17,6 +17,7 @@ import sys
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs.adfll_dqn import ADFLLConfig, DQNConfig
 from repro.core.erb import TaskTag, erb_add, erb_flatten, erb_init
@@ -475,6 +476,17 @@ def test_sharded_mesh_bit_identity_and_no_recompile():
     )
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "MESH-OK" in proc.stdout
+
+
+def test_fleet_mesh_never_shrinks_silently():
+    from repro.models.sharding import make_fleet_mesh
+
+    n = len(jax.devices())
+    assert make_fleet_mesh() is None or make_fleet_mesh().size <= n
+    assert make_fleet_mesh(1) is None
+    for bad in (n + 1, 2 * n, 3):  # more than visible, or not a power of two
+        with pytest.raises(ValueError):
+            make_fleet_mesh(bad)
 
 
 def test_agent_sampler_inherits_use_pallas_flag():

@@ -15,7 +15,7 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 def test_rmsnorm_matches_ref(rng, shape, dtype, atol):
     x = jnp.asarray(rng.standard_normal(shape), dtype)
     scale = jnp.asarray(rng.standard_normal(shape[-1]), jnp.float32)
-    out = rmsnorm(x, scale, block_rows=4)
+    out = rmsnorm(x, scale, block_rows=4, interpret=True)
     ref = rmsnorm_ref(x, scale)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=atol, rtol=atol
@@ -28,7 +28,7 @@ def test_rmsnorm_property_sweep(n, d, seed):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     scale = jnp.asarray(rng.standard_normal(d), jnp.float32)
-    out = np.asarray(rmsnorm(x, scale, block_rows=8))
+    out = np.asarray(rmsnorm(x, scale, block_rows=8, interpret=True))
     ref = np.asarray(rmsnorm_ref(x, scale))
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
     # unit RMS after normalization (pre-scale) is the invariant

@@ -308,6 +308,23 @@ def test_worker_exception_records_an_error_cell(tmp_path, monkeypatch):
     assert not failed_cells(summary2)
 
 
+def test_accelerator_host_runs_cells_inline(monkeypatch):
+    # off the CPU a spawned worker would need the chip the parent holds:
+    # the sweep runs inline (the stubbed runner is only visible here)
+    calls = []
+
+    def fake_run(spec, **kw):
+        calls.append(spec.name)
+        return _fake_report(spec)
+
+    monkeypatch.setattr(runner, "run", fake_run)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    said = []
+    summary = run_sweep(_tiny_sweep(seeds=(0,)), workers=4, echo=said.append)
+    assert len(calls) == 2 and not failed_cells(summary)
+    assert any("inline" in line for line in said)
+
+
 # ---------------------------------------------------------------------------
 # aggregation + compare
 # ---------------------------------------------------------------------------
