@@ -481,50 +481,65 @@ def collect_fleet(agents, envs, erbs, n_episodes: int) -> None:
     if not agents:
         return
     engine = agents[0].engine
+    tel = engine.telemetry
     cfg = agents[0].cfg
     steps = make_collect_steps(cfg, mesh=engine.mesh)
     n = len(agents)
     n_min = engine.mesh.size if engine.mesh is not None else 1
     a_pad = max(_pow2(n), n_min)
     slots = [a.slot for a in agents] + [agents[0].slot] * (a_pad - n)
+    # may flush the cohort's pending jobs: outside the collect span, so
+    # fleet.flush and fleet.collect never nest
     stacked = engine.padded_slot_params(slots)
     box = cfg.box_size
     b = n_episodes
-    locs = np.stack([env.start_locs(b, a.rng) for a, env in zip(agents, envs)])
-    alive = np.ones((n, b), bool)
-    obs_buf = np.zeros((a_pad, b, *box), np.float32)
-    loc_buf = np.zeros((a_pad, b, 3), np.float32)
-    for _ in range(cfg.max_episode_steps):
-        live = [i for i in range(n) if alive[i].any()]
-        if not live:
-            break
-        for i in live:
-            obs_buf[i] = envs[i].observe(locs[i])
-            loc_buf[i] = envs[i].norm_loc(locs[i])
-        q = np.asarray(steps.qvals(stacked, jnp.asarray(obs_buf), jnp.asarray(loc_buf)))
-        for i in live:
-            agent, env, erb = agents[i], envs[i], erbs[i]
-            eps = agent.epsilon()
-            greedy = q[i].argmax(-1)
-            rand = agent.rng.integers(0, cfg.n_actions, size=b)
-            coin = agent.rng.random(b) < eps
-            acts = np.where(coin, rand, greedy).astype(np.int32)
-            new, r, done = env.step(locs[i], acts)
-            idx = np.where(alive[i])[0]
-            batch = {
-                # obs_buf[i] is env.observe(locs[i]) — reuse the staged
-                # rows instead of re-cropping for the ERB append
-                "obs": obs_buf[i][idx],
-                "loc": loc_buf[i][idx],
-                "action": acts[idx],
-                "reward": r[idx],
-                "next_obs": env.observe(new[idx]),
-                "next_loc": env.norm_loc(new[idx]),
-                "done": done[idx].astype(np.float32),
-            }
-            erb_add(erb, batch)
-            locs[i] = new
-            alive[i] &= ~done
+    with tel.wall_span("fleet.collect", "fleet", agents=n) as span:
+        locs = np.stack([env.start_locs(b, a.rng) for a, env in zip(agents, envs)])
+        alive = np.ones((n, b), bool)
+        obs_buf = np.zeros((a_pad, b, *box), np.float32)
+        loc_buf = np.zeros((a_pad, b, 3), np.float32)
+        dispatches = env_steps = 0
+        for _ in range(cfg.max_episode_steps):
+            live = [i for i in range(n) if alive[i].any()]
+            if not live:
+                break
+            for i in live:
+                obs_buf[i] = envs[i].observe(locs[i])
+                loc_buf[i] = envs[i].norm_loc(locs[i])
+            q = np.asarray(
+                steps.qvals(stacked, jnp.asarray(obs_buf), jnp.asarray(loc_buf))
+            )
+            dispatches += 1
+            for i in live:
+                agent, env, erb = agents[i], envs[i], erbs[i]
+                eps = agent.epsilon()
+                greedy = q[i].argmax(-1)
+                rand = agent.rng.integers(0, cfg.n_actions, size=b)
+                coin = agent.rng.random(b) < eps
+                acts = np.where(coin, rand, greedy).astype(np.int32)
+                new, r, done = env.step(locs[i], acts)
+                idx = np.where(alive[i])[0]
+                batch = {
+                    # obs_buf[i] is env.observe(locs[i]) — reuse the staged
+                    # rows instead of re-cropping for the ERB append
+                    "obs": obs_buf[i][idx],
+                    "loc": loc_buf[i][idx],
+                    "action": acts[idx],
+                    "reward": r[idx],
+                    "next_obs": env.observe(new[idx]),
+                    "next_loc": env.norm_loc(new[idx]),
+                    "done": done[idx].astype(np.float32),
+                }
+                erb_add(erb, batch)
+                env_steps += len(idx)
+                locs[i] = new
+                alive[i] &= ~done
+        if tel.enabled:
+            span.update(
+                dispatches=dispatches,
+                env_steps=env_steps,
+                h2d_bytes=dispatches * (obs_buf.nbytes + loc_buf.nbytes),
+            )
 
 
 class ActSteps:
@@ -811,19 +826,18 @@ class FleetEngine:
         self._set_field(slot, "opt", opt)
 
     # -- replay pool ----------------------------------------------------------
-    def _flat_erb(self, erb: ERB) -> jax.Array:
+    def _flat_erb(self, erb: ERB) -> tuple[jax.Array, bool]:
         """Device-resident ``[size, 1, pool_width(F)]`` rows of an ERB (the
         gather kernel's row view, zero lanes on the right; LRU-cached; keyed
         by (erb_id, version) so host-side ring appends invalidate; bounded
         by entry count *and* total bytes — at paper-scale buffers the byte
-        budget binds first)."""
+        budget binds first), and whether they came from the cache (False:
+        they were uploaded now)."""
         key = (erb.meta.erb_id, erb.version)
         hit = self._erb_cache.get(key)
         if hit is not None:
             self._erb_cache.move_to_end(key)
-            self.telemetry.count("fleet.erb_cache.hits", 1)
-            return hit
-        self.telemetry.count("fleet.erb_cache.misses", 1)
+            return hit, True
         flat = jnp.asarray(erb_flatten(erb, self._feat)[:, None, :])
         self._erb_cache[key] = flat
         self._erb_cache_nbytes += flat.nbytes
@@ -833,8 +847,7 @@ class FleetEngine:
         ):
             _, evicted = self._erb_cache.popitem(last=False)
             self._erb_cache_nbytes -= evicted.nbytes
-            self.telemetry.count("fleet.erb_cache.evictions", 1)
-        return flat
+        return flat, False
 
     # -- job queue ------------------------------------------------------------
     def submit(self, slot: int, plans: Sequence) -> TrainFuture:
@@ -887,102 +900,112 @@ class FleetEngine:
 
     def _flush_group(self, jobs: list[_Job]) -> None:
         tel = self.telemetry
-        wall0 = tel.wall() if tel.enabled else 0.0
-        traces0 = self.steps.n_traces
-        n_real = len(jobs)
-        k_steps = jobs[0].n_steps
-        batch = jobs[0].eidx.shape[1]
-        # one shared device pool: the union of every job's ERBs
-        offsets: dict[str, int] = {}
-        parts: list[jax.Array] = []
-        total = 0
-        for job in jobs:
-            for erb in job.erbs:
-                if erb.meta.erb_id not in offsets:
-                    offsets[erb.meta.erb_id] = total
-                    total += erb.size
-                    parts.append(self._flat_erb(erb))
-        # bucket pool rows and job count (powers of two, mesh-divisible)
-        # to bound the number of compiled (K, N, R) shape variants
-        r_pad = max(self.pool_bucket_floor, _pow2(total))
-        if r_pad > total:
-            parts.append(jnp.zeros((r_pad - total, 1, self._feat), jnp.float32))
-        pool = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-        n_min = self.mesh.size if self.mesh is not None else 1
-        n_pad = max(_pow2(n_real), n_min)
-        slots = [job.slot for job in jobs]
-        # whole-bucket fast path: the jobs cover every live slot in order,
-        # so the resident state IS the chunk operand — no gather in, no
-        # scatter out, and the donated buffers flow through the flush end
-        # to end (padding lanes train on pool row 0; those rows are dead
-        # slots, never read, overwritten on slot reuse)
-        resident = (
-            n_real == self.n_slots
-            and slots == list(range(n_real))
-            and n_pad == self.capacity
-        )
-        idx = np.zeros((k_steps, n_pad, batch), np.int32)
-        for jpos, job in enumerate(jobs):
-            base = np.array([offsets[e.meta.erb_id] for e in job.erbs], np.int32)
-            idx[:, jpos, :] = base[job.eidx] + job.rows
-        if resident:
-            sub = self.state
-        else:
-            padded = slots + [slots[0]] * (n_pad - n_real)  # inert duplicates
-            gather = jnp.asarray(padded)
-            sub = jax.tree_util.tree_map(
-                lambda x: jnp.take(x, gather, axis=0), self.state
+        with tel.wall_span("fleet.flush", "fleet") as span:
+            traces0 = self.steps.n_traces
+            n_real = len(jobs)
+            k_steps = jobs[0].n_steps
+            batch = jobs[0].eidx.shape[1]
+            with tel.wall_span("fleet.flush.pool", "fleet") as pool_span:
+                # one shared device pool: the union of every job's ERBs
+                offsets: dict[str, int] = {}
+                parts: list[jax.Array] = []
+                total = n_cached = uploaded = 0
+                for job in jobs:
+                    for erb in job.erbs:
+                        if erb.meta.erb_id not in offsets:
+                            offsets[erb.meta.erb_id] = total
+                            total += erb.size
+                            flat, cached = self._flat_erb(erb)
+                            parts.append(flat)
+                            n_cached += cached
+                            uploaded += 0 if cached else flat.nbytes
+                # bucket pool rows and job count (powers of two,
+                # mesh-divisible) to bound the number of compiled (K, N, R)
+                # shape variants
+                r_pad = max(self.pool_bucket_floor, _pow2(total))
+                if r_pad > total:
+                    parts.append(jnp.zeros((r_pad - total, 1, self._feat), jnp.float32))
+                pool = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+                if tel.enabled:
+                    pool_span.update(
+                        erbs=len(offsets), erbs_cached=n_cached, h2d_bytes=uploaded
+                    )
+            n_min = self.mesh.size if self.mesh is not None else 1
+            n_pad = max(_pow2(n_real), n_min)
+            slots = [job.slot for job in jobs]
+            # whole-bucket fast path: the jobs cover every live slot in
+            # order, so the resident state IS the chunk operand — no gather
+            # in, no scatter out, and the donated buffers flow through the
+            # flush end to end (padding lanes train on pool row 0; those
+            # rows are dead slots, never read, overwritten on slot reuse)
+            resident = (
+                n_real == self.n_slots
+                and slots == list(range(n_real))
+                and n_pad == self.capacity
             )
-            if self.sharding is not None:
-                # the gather commits its output replicated; re-place so
-                # the operand matches the chunk's explicit in_shardings
-                sub = self.sharding.place(sub)
-        obs = self.observatory
-        stats = None
-        if obs is None:
-            new, losses = self.steps.train_chunk(sub, pool, jnp.asarray(idx))
-        else:
-            new, stats = self.steps.train_chunk_stats(sub, pool, jnp.asarray(idx))
-            losses = stats["loss"]
-        if resident:
-            self.state = new
-        else:
-            real = jnp.asarray(slots)
-            self.state = jax.tree_util.tree_map(
-                lambda s, ns: s.at[real].set(ns[:n_real]), self.state, new
-            )
-            if self.sharding is not None:
-                self.state = self.sharding.place(self.state)
-        self._views.clear()
-        losses_np = np.asarray(losses)  # the flush's one host sync
-        if obs is not None and stats is not None:
-            # drained at the same boundary — no extra mid-scan syncs,
-            # just more values riding the flush's host transfer
-            stats_np = {k: np.asarray(v) for k, v in stats.items()}
-            sim_t = self.sim_clock() if self.sim_clock is not None else 0.0
-            obs.on_flush(slots, stats_np, n_real, sim_t)
-        self.n_flushes += 1
-        self.n_steps_trained += n_real * k_steps
-        self.flush_sizes.append(n_real)
-        if tel.enabled:
-            wall1 = tel.wall()
+            obs = self.observatory
+            stats = None
+            with tel.wall_span("fleet.flush.dispatch", "fleet"):
+                idx = np.zeros((k_steps, n_pad, batch), np.int32)
+                for jpos, job in enumerate(jobs):
+                    base = np.array(
+                        [offsets[e.meta.erb_id] for e in job.erbs], np.int32
+                    )
+                    idx[:, jpos, :] = base[job.eidx] + job.rows
+                if resident:
+                    sub = self.state
+                else:
+                    padded = slots + [slots[0]] * (n_pad - n_real)  # inert duplicates
+                    gather = jnp.asarray(padded)
+                    sub = jax.tree_util.tree_map(
+                        lambda x: jnp.take(x, gather, axis=0), self.state
+                    )
+                    if self.sharding is not None:
+                        # the gather commits its output replicated; re-place
+                        # so the operand matches the chunk's in_shardings
+                        sub = self.sharding.place(sub)
+                if obs is None:
+                    new, losses = self.steps.train_chunk(sub, pool, jnp.asarray(idx))
+                else:
+                    new, stats = self.steps.train_chunk_stats(
+                        sub, pool, jnp.asarray(idx)
+                    )
+                    losses = stats["loss"]
+                if resident:
+                    self.state = new
+                else:
+                    real = jnp.asarray(slots)
+                    self.state = jax.tree_util.tree_map(
+                        lambda s, ns: s.at[real].set(ns[:n_real]), self.state, new
+                    )
+                    if self.sharding is not None:
+                        self.state = self.sharding.place(self.state)
+                self._views.clear()
+            with tel.wall_span("fleet.flush.wait", "fleet"):
+                losses_np = np.asarray(losses)  # the flush's one host sync
+            if obs is not None and stats is not None:
+                # drained at the same boundary — no extra mid-scan syncs,
+                # just more values riding the flush's host transfer
+                stats_np = {k: np.asarray(v) for k, v in stats.items()}
+                sim_t = self.sim_clock() if self.sim_clock is not None else 0.0
+                obs.on_flush(slots, stats_np, n_real, sim_t)
+            self.n_flushes += 1
+            self.n_steps_trained += n_real * k_steps
+            self.flush_sizes.append(n_real)
             compiled = self.steps.n_traces - traces0
-            tel.span(
-                "fleet.flush",
-                "fleet",
-                wall0,
-                wall1,
-                clock="wall",
-                jobs=n_real,
-                k_steps=k_steps,
-                batch=batch,
-                pool_rows=int(r_pad),
-                devices=n_min,
-                resident=resident,
-                compiled=compiled,
-            )
+            if tel.enabled:
+                span.update(
+                    jobs=n_real,
+                    k_steps=k_steps,
+                    batch=batch,
+                    pool_rows=int(r_pad),
+                    devices=n_min,
+                    resident=resident,
+                    compiled=compiled,
+                )
+        if tel.enabled:
             if compiled:
-                tel.instant("fleet.compile", "fleet", wall1, clock="wall")
+                tel.instant("fleet.compile", "fleet", tel.wall(), clock="wall")
                 tel.count("fleet.compiles", compiled)
             if self.sim_clock is not None:
                 # the same flush pinned to simulated time, so trace views
@@ -990,8 +1013,6 @@ class FleetEngine:
                 tel.instant("fleet.flush", "fleet", self.sim_clock(), jobs=n_real)
             tel.count("fleet.flushes", 1)
             tel.count("fleet.steps_trained", n_real * k_steps)
-            tel.observe("fleet.flush.jobs", n_real)
-            tel.observe("fleet.flush.wall_s", wall1 - wall0)
         for jpos, job in enumerate(jobs):
             job.future.resolve(float(losses_np[-1, jpos]))
 

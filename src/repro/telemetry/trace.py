@@ -7,7 +7,10 @@ A :class:`Tracer` records a flat list of events.  Every event carries a
   Scheduler` time).  Round phases, gossip exchanges, availability windows.
 - ``"wall"``  — host ``perf_counter`` seconds.  Fleet flushes, serve
   ticks, XLA compiles — things that cost real time regardless of the
-  simulated clock.
+  simulated clock.  A span opened with :meth:`Tracer.wall_span` is also
+  a ``jax.profiler.TraceAnnotation``: while a JAX profiler trace is
+  recording, it appears there as a host event of the same name, on the
+  clock of the device's operations.
 
 Event kinds mirror the Chrome ``trace_event`` phases they export to:
 
@@ -36,6 +39,8 @@ from collections.abc import Iterator
 from contextlib import contextmanager
 from pathlib import Path
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 from .registry import MetricsRegistry, NullRegistry
 
@@ -199,11 +204,18 @@ class Tracer:
         )
 
     @contextmanager
-    def wall_span(self, name: str, track: str, **args) -> Iterator[None]:
-        """Context manager recording a wall-clock span around its body."""
+    def wall_span(self, name: str, track: str, **args) -> Iterator[dict[str, Any]]:
+        """Context manager recording a wall-clock span around its body.
+
+        Yields the span's attribute dict: the body may add attributes
+        known only at its end (bytes moved, counts), and the span records
+        them. The body runs inside a ``jax.profiler.TraceAnnotation`` of
+        the same name, so a profiler trace holds the span as a host event
+        on the device trace's clock."""
         t0 = self.wall()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield args
         finally:
             self.span(name, track, t0, self.wall(), clock="wall", **args)
 

@@ -27,6 +27,7 @@ from repro.rl.agent import DQNAgent, dqn_step_traces, make_dqn_steps
 from repro.rl.env import LandmarkEnv
 from repro.rl.fleet import FleetEngine, collect_fleet, make_fleet_steps
 from repro.rl.synth import make_volume, paper_eight_tasks, patient_split
+from repro.telemetry import Telemetry
 
 DQN = DQNConfig(
     volume_shape=(16, 16, 16),
@@ -343,6 +344,101 @@ def test_padded_capacity_and_dead_slot_hygiene():
     # growing past the bucket boundary re-tiles to the next power of two
     DQNAgent(4, cfg, seed=4, engine=engine)
     assert engine.n_slots == 5 and engine.capacity == 8
+
+
+# -- spans inside the fleet ----------------------------------------------------
+FLUSH_CHILDREN = ("fleet.flush.pool", "fleet.flush.dispatch", "fleet.flush.wait")
+
+
+def test_flush_children_nest_inside_the_flush_span():
+    """Each flush holds one pool, one dispatch and one wait span, inside
+    its own interval and together no longer than it; the flush keeps its
+    attributes."""
+    cfg = dataclasses.replace(DQN, eps_decay_steps=503)
+    engine = FleetEngine(cfg)
+    tel = engine.telemetry = Telemetry()
+    agents = [DQNAgent(i, cfg, seed=i, engine=engine) for i in range(3)]
+    erb = _filled_erb(np.random.default_rng(4))
+    for order in (agents, agents[::-1], agents[:1]):
+        for a in order:
+            a._submit_steps(3, erb, ())
+        engine.flush()
+    flushes = tel.tracer.spans("fleet.flush")
+    assert [f["args"]["jobs"] for f in flushes] == [3, 3, 1]
+    for f in flushes:
+        assert set(f["args"]) == {
+            "jobs",
+            "k_steps",
+            "batch",
+            "pool_rows",
+            "devices",
+            "resident",
+            "compiled",
+        }
+        inside = [
+            e
+            for e in tel.tracer.spans()
+            if e["name"] in FLUSH_CHILDREN and f["t0"] <= e["t0"] <= e["t1"] <= f["t1"]
+        ]
+        assert sorted(e["name"] for e in inside) == sorted(FLUSH_CHILDREN)
+        assert sum(e["t1"] - e["t0"] for e in inside) <= f["t1"] - f["t0"]
+    for name in FLUSH_CHILDREN:
+        assert len(tel.tracer.spans(name)) == len(flushes)
+
+
+def test_collect_span_counts_env_steps_and_uploads():
+    """One ``fleet.collect`` span per ``collect_fleet`` call, whose
+    ``env_steps`` are the rows appended to the ERBs and whose bytes are
+    the staged crop and location batches of each dispatch."""
+    cfg = dataclasses.replace(DQN, max_episode_steps=8, eps_decay_steps=509)
+    engine = FleetEngine(cfg)
+    tel = engine.telemetry = Telemetry()
+    agents = [DQNAgent(i, cfg, seed=i, engine=engine) for i in range(3)]
+    task = TaskTag("t1", "axial", "HGG")
+    vol, lm = make_volume(task, 2, n=16)
+    appended = []
+    for episodes in (6, 4):
+        erbs = [erb_init(256, cfg.box_size, task=task) for _ in agents]
+        envs = [LandmarkEnv(vol, lm, cfg) for _ in agents]
+        collect_fleet(agents, envs, erbs, episodes)
+        appended.append(sum(e.size for e in erbs))
+    spans = tel.tracer.spans("fleet.collect")
+    assert len(spans) == 2
+    for span, rows, episodes in zip(spans, appended, (6, 4)):
+        a = span["args"]
+        assert a["agents"] == 3 and a["env_steps"] == rows > 0
+        assert 1 <= a["dispatches"] <= cfg.max_episode_steps
+        # a 3-agent cohort runs as a 4-lane bucket
+        staged = 4 * episodes * (np.prod(cfg.box_size) + 3) * 4
+        assert a["h2d_bytes"] == a["dispatches"] * staged
+
+
+def test_pool_span_counts_uploads_and_cache_hits():
+    """``h2d_bytes`` on ``fleet.flush.pool`` is the bytes of the ERB rows
+    uploaded on cache misses; ``erbs_cached`` counts the hits."""
+    cfg = dataclasses.replace(DQN, eps_decay_steps=521)
+    engine = FleetEngine(cfg)
+    tel = engine.telemetry = Telemetry()
+    a0, a1 = (DQNAgent(i, cfg, seed=i, engine=engine) for i in range(2))
+    rng = np.random.default_rng(6)
+    erb_a, erb_b = _filled_erb(rng, 128), _filled_erb(rng, 64)
+
+    def nbytes(erb):
+        return erb_flatten(erb, engine._feat)[:, None, :].nbytes
+
+    def flush(*pairs):
+        for agent, erb in pairs:
+            agent._submit_steps(2, erb, ())
+        engine.flush()
+        return tel.tracer.spans("fleet.flush.pool")[-1]["args"]
+
+    cold = {"erbs": 2, "erbs_cached": 0, "h2d_bytes": nbytes(erb_a) + nbytes(erb_b)}
+    assert flush((a0, erb_a), (a1, erb_b)) == cold
+    hits = {"erbs": 2, "erbs_cached": 2, "h2d_bytes": 0}
+    assert flush((a0, erb_b), (a1, erb_a)) == hits
+    erb_add(erb_b, {k: v[:8] for k, v in erb_b.data.items()})  # new version
+    one_new = {"erbs": 2, "erbs_cached": 1, "h2d_bytes": nbytes(erb_b)}
+    assert flush((a0, erb_a), (a1, erb_b)) == one_new
 
 
 # -- device-mesh sharding (8 host-platform devices, subprocess) --------------

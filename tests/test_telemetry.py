@@ -137,8 +137,15 @@ def _fingerprint(report):
 
 def test_disabled_telemetry_is_bit_identical():
     base = run(_tiny_spec())
-    off = run(_tiny_spec(), telemetry=Telemetry(enabled=False))
+    tel = Telemetry(enabled=False)
+    off = run(_tiny_spec(), telemetry=tel)
     assert _fingerprint(base) == _fingerprint(off)
+    # the fleet's collect and flush spans ran on the disabled bundle and
+    # recorded nothing
+    assert len(tel.tracer) == 0 and list(tel.registry.rows()) == []
+    with tel.wall_span("fleet.flush", "fleet", jobs=1) as attrs:
+        assert attrs is None
+    assert len(tel.tracer) == 0
 
 
 def test_enabled_telemetry_is_observe_only_and_captures_spans(tmp_path):
@@ -152,6 +159,50 @@ def test_enabled_telemetry_is_observe_only_and_captures_spans(tmp_path):
     # registry carries the same byte totals the report already reports
     erb = tel.registry.counter_value("comm.bytes", plane="erb")
     assert erb == traced.summary()["bytes_by_plane"].get("erb", 0)
+
+
+def test_wall_span_records_attributes_set_in_its_body():
+    tel = Telemetry(enabled=True)
+    with tel.wall_span("fleet.collect", "fleet", agents=2) as attrs:
+        attrs["h2d_bytes"] = 1024
+    (span,) = tel.tracer.spans("fleet.collect")
+    assert span["clock"] == "wall" and span["track"] == "fleet"
+    assert span["args"] == {"agents": 2, "h2d_bytes": 1024}
+    assert 0.0 <= span["t0"] <= span["t1"]
+
+
+def test_wall_span_is_an_annotation_in_the_profiler_trace(tmp_path):
+    """A recorded profiler trace holds each wall span as a host event of
+    the same name, on the trace's own clock: durations and the distance
+    between two spans' starts agree with the tracer's within 1 ms."""
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tel = Telemetry(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    with tel.wall_span("probe.a", "fleet"):
+        time.sleep(0.02)
+    time.sleep(0.01)
+    with tel.wall_span("probe.b", "fleet"):
+        time.sleep(0.03)
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = {
+        ev.name: (ev.start_ns * 1e-9, ev.duration_ns * 1e-9)
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name in ("probe.a", "probe.b")
+    }
+    a, b = tel.tracer.spans("probe.a")[0], tel.tracer.spans("probe.b")[0]
+    assert set(host) == {"probe.a", "probe.b"}
+    assert host["probe.a"][1] == pytest.approx(a["t1"] - a["t0"], abs=1e-3)
+    assert host["probe.b"][1] == pytest.approx(b["t1"] - b["t0"], abs=1e-3)
+    assert host["probe.b"][0] - host["probe.a"][0] == pytest.approx(
+        b["t0"] - a["t0"], abs=1e-3
+    )
 
 
 # ---------------------------------------------------------------------------
