@@ -62,30 +62,34 @@ class LandmarkEnv:
     landmark: np.ndarray  # [3] float (zyx)
     cfg: DQNConfig
     # pad-once cache: np.pad of the full volume on *every* observe call
-    # dominated the host-side round cost before the batched gather below
+    # would cost more than the crops themselves
     _padded: np.ndarray | None = field(default=None, repr=False, compare=False)
 
     @property
     def n(self) -> int:
         return self.volume.shape[0]
 
-    def observe(self, locs: np.ndarray) -> np.ndarray:
-        """locs [B,3] int -> crops [B, bx,by,bz] centered at locs
-        (zero-padded at boundaries). One batched fancy-index gather from
-        a cached zero-padded volume — no per-row Python loop."""
-        bx, by, bz = self.cfg.box_size
-        half = np.array([bx // 2, by // 2, bz // 2])
-        pad = max(bx, by, bz)
+    def observe(self, locs: np.ndarray, out: np.ndarray | None = None) -> np.ndarray:
+        """locs [B,3] int -> crops [B, bx,by,bz] float32 centered at locs
+        (zero-padded at boundaries), written into ``out`` when given.
+
+        Each window is one basic slice of a cached zero-padded volume,
+        copied row by row: a broadcast fancy index over the same windows
+        costs about ten times as much on the host. A window that leaves
+        the padded volume raises ``IndexError``."""
+        box = self.cfg.box_size
+        pad = max(box)
         if self._padded is None:
             self._padded = np.pad(self.volume, pad)
-        c = locs + pad - half  # [B,3] window starts
-        iz = c[:, 0, None] + np.arange(bx)  # [B,bx]
-        iy = c[:, 1, None] + np.arange(by)  # [B,by]
-        ix = c[:, 2, None] + np.arange(bz)  # [B,bz]
-        out = self._padded[
-            iz[:, :, None, None], iy[:, None, :, None], ix[:, None, None, :]
-        ]
-        return np.ascontiguousarray(out, dtype=np.float32)
+        starts = np.asarray(locs) + pad - np.asarray(box) // 2  # [B,3]
+        if (starts < 0).any() or (starts + box > self._padded.shape).any():
+            raise IndexError(f"crop window outside the padded volume at {locs}")
+        if out is None:
+            out = np.empty((len(starts), *box), np.float32)
+        bx, by, bz = box
+        for row, (z, y, x) in zip(out, starts.tolist(), strict=True):
+            row[...] = self._padded[z : z + bx, y : y + by, x : x + bz]
+        return out
 
     def norm_loc(self, locs: np.ndarray) -> np.ndarray:
         return locs.astype(np.float32) / (self.n - 1)

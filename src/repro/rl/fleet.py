@@ -477,6 +477,11 @@ def collect_fleet(agents, envs, erbs, n_episodes: int) -> None:
     identical order. Agents whose episodes all finish early stop
     consuming their rng and stop writing their ERB, exactly like the
     per-agent loop's early ``break``.
+
+    Each location is cropped once: the crops of a step's new locations
+    are both that step's ``next_obs`` and the next dispatch's staged
+    observations (the per-agent loop crops them twice). The
+    ``fleet.collect`` span counts the rows cropped as ``crop_rows``.
     """
     if not agents:
         return
@@ -498,13 +503,16 @@ def collect_fleet(agents, envs, erbs, n_episodes: int) -> None:
         alive = np.ones((n, b), bool)
         obs_buf = np.zeros((a_pad, b, *box), np.float32)
         loc_buf = np.zeros((a_pad, b, 3), np.float32)
+        for i in range(n):
+            envs[i].observe(locs[i], out=obs_buf[i])
+        crop_rows = n * b
         dispatches = env_steps = 0
-        for _ in range(cfg.max_episode_steps):
+        last = cfg.max_episode_steps - 1
+        for t in range(cfg.max_episode_steps):
             live = [i for i in range(n) if alive[i].any()]
             if not live:
                 break
             for i in live:
-                obs_buf[i] = envs[i].observe(locs[i])
                 loc_buf[i] = envs[i].norm_loc(locs[i])
             q = np.asarray(
                 steps.qvals(stacked, jnp.asarray(obs_buf), jnp.asarray(loc_buf))
@@ -519,25 +527,36 @@ def collect_fleet(agents, envs, erbs, n_episodes: int) -> None:
                 acts = np.where(coin, rand, greedy).astype(np.int32)
                 new, r, done = env.step(locs[i], acts)
                 idx = np.where(alive[i])[0]
+                # obs_buf[i] holds this step's crops at locs[i]: copy the
+                # live rows out before the next crops overwrite them
+                obs = obs_buf[i][idx]
+                alive[i] &= ~done
+                if t < last and alive[i].any():
+                    # the next dispatch stages every row at new (dead rows
+                    # keep moving): one crop is also this step's next_obs
+                    env.observe(new, out=obs_buf[i])
+                    next_obs = obs_buf[i] if len(idx) == b else obs_buf[i][idx]
+                    crop_rows += b
+                else:
+                    next_obs = env.observe(new[idx])
+                    crop_rows += len(idx)
                 batch = {
-                    # obs_buf[i] is env.observe(locs[i]) — reuse the staged
-                    # rows instead of re-cropping for the ERB append
-                    "obs": obs_buf[i][idx],
+                    "obs": obs,
                     "loc": loc_buf[i][idx],
                     "action": acts[idx],
                     "reward": r[idx],
-                    "next_obs": env.observe(new[idx]),
+                    "next_obs": next_obs,
                     "next_loc": env.norm_loc(new[idx]),
                     "done": done[idx].astype(np.float32),
                 }
                 erb_add(erb, batch)
                 env_steps += len(idx)
                 locs[i] = new
-                alive[i] &= ~done
         if tel.enabled:
             span.update(
                 dispatches=dispatches,
                 env_steps=env_steps,
+                crop_rows=crop_rows,
                 h2d_bytes=dispatches * (obs_buf.nbytes + loc_buf.nbytes),
             )
 

@@ -25,7 +25,12 @@ from repro.core.federated import ADFLLSystem
 from repro.core.replay import SelectiveReplaySampler
 from repro.rl.agent import DQNAgent, dqn_step_traces, make_dqn_steps
 from repro.rl.env import LandmarkEnv
-from repro.rl.fleet import FleetEngine, collect_fleet, make_fleet_steps
+from repro.rl.fleet import (
+    FleetEngine,
+    collect_fleet,
+    make_collect_steps,
+    make_fleet_steps,
+)
 from repro.rl.synth import make_volume, paper_eight_tasks, patient_split
 from repro.telemetry import Telemetry
 
@@ -257,21 +262,43 @@ def _observe_reference(env: LandmarkEnv, locs: np.ndarray) -> np.ndarray:
 
 
 def test_observe_matches_loop_reference(rng):
-    vol, lm = make_volume(TaskTag("t2", "axial", "LGG"), 4, n=16)
-    env = LandmarkEnv(vol, lm, DQN)
-    n = env.n
-    locs = np.concatenate(
-        [
-            rng.integers(0, n, size=(32, 3)),
-            np.array([[0, 0, 0], [n - 1, n - 1, n - 1], [0, n - 1, 7]]),
-        ]
-    ).astype(np.int32)
-    want = _observe_reference(env, locs)
-    got = env.observe(locs)
-    assert got.dtype == np.float32 and got.flags["C_CONTIGUOUS"]
-    np.testing.assert_array_equal(got, want)
-    # second call exercises the pad-once cache
-    np.testing.assert_array_equal(env.observe(locs), want)
+    """Bitwise the per-row slice loop's crops, float32 and C-contiguous:
+    at random locations, the corners and edges, for one row and for none,
+    with a small box, a box of unequal sides, and the paper's 45^3 box on
+    a volume barely larger than it."""
+    for side, box in ((16, DQN.box_size), (16, (7, 4, 5)), (46, (45, 45, 45))):
+        cfg = dataclasses.replace(DQN, volume_shape=(side,) * 3, box_size=box)
+        vol, lm = make_volume(TaskTag("t2", "axial", "LGG"), 4, n=side)
+        env = LandmarkEnv(vol, lm, cfg)
+        n, m = env.n - 1, env.n // 2
+        corners = np.array(np.meshgrid([0, n], [0, n], [0, n])).reshape(3, -1).T
+        edges = np.array([[0, m, n], [n, 0, m], [m, n, 0], [0, 0, m], [n, n, m]])
+        locs = np.concatenate(
+            [rng.integers(0, env.n, size=(32, 3)), corners, edges]
+        ).astype(np.int32)
+        for batch in (locs, locs[-1:], locs[:0]):
+            want = _observe_reference(env, batch)
+            got = env.observe(batch)
+            assert got.shape == (len(batch), *box)
+            assert got.dtype == np.float32 and got.flags["C_CONTIGUOUS"]
+            np.testing.assert_array_equal(got, want)
+        # a second call exercises the pad-once cache; out= is written in place
+        out = np.full((len(locs), *box), np.nan, np.float32)
+        assert env.observe(locs, out=out) is out
+        np.testing.assert_array_equal(out, _observe_reference(env, locs))
+
+
+@pytest.mark.parametrize(
+    "loc", [[-100, 0, 0], [0, 200, 0], [0, 0, -46], [0, 0, 46 + 23]]
+)
+def test_observe_rejects_windows_outside_the_volume(loc):
+    """A location whose window leaves the zero-padded volume raises; it
+    never wraps round or returns a short window."""
+    cfg = dataclasses.replace(DQN, volume_shape=(46,) * 3, box_size=(45, 45, 45))
+    vol, lm = make_volume(TaskTag("t2", "axial", "LGG"), 4, n=46)
+    env = LandmarkEnv(vol, lm, cfg)
+    with pytest.raises(IndexError):
+        env.observe(np.array([[1, 2, 3], loc], np.int32))
 
 
 # -- stacked collection == per-agent collection ------------------------------
@@ -314,6 +341,70 @@ def test_agent_collect_routes_through_stacked_program():
     assert erb_f.size == erb_l.size > 0
     np.testing.assert_array_equal(erb_flatten(erb_f), erb_flatten(erb_l))
     assert fa.rng.bit_generator.state == la.rng.bit_generator.state
+
+
+def _counting(observe, rows: list):
+    """``observe`` that adds the rows it crops to ``rows[0]``."""
+
+    def counted(locs, out=None):
+        rows[0] += len(locs)
+        return observe(locs, out=out)
+
+    return counted
+
+
+def test_collect_fleet_matches_stepwise_when_episodes_end_early():
+    """With the landmark inside the start region of a small volume, rows
+    and whole agents finish early. The reused crops still give the
+    stepwise agents' ERB bytes and rng states, and every observation row
+    staged for a dispatch (dead rows and finished agents included) is
+    the crop at the location staged beside it."""
+    cfg = dataclasses.replace(
+        DQN, volume_shape=(5, 5, 5), box_size=(4, 5, 3), max_episode_steps=16
+    )
+    vol = np.random.default_rng(11).random((5, 5, 5)).astype(np.float32)
+    lm = np.array([2.0, 2.0, 2.0])
+    engine = FleetEngine(cfg)
+    tel = engine.telemetry = Telemetry()
+    fleet = [DQNAgent(i, cfg, seed=40 + i, engine=engine) for i in range(3)]
+    legacy = [DQNAgent(i, cfg, seed=40 + i, backend="stepwise") for i in range(3)]
+    task = TaskTag("t1", "axial", "HGG")
+    erbs_f = [erb_init(512, cfg.box_size, task=task) for _ in fleet]
+    erbs_l = [erb_init(512, cfg.box_size, task=task) for _ in legacy]
+    steps = make_collect_steps(cfg)
+    staged = []
+
+    def qvals(stacked, obs, loc):
+        staged.append((np.array(obs), np.array(loc)))
+        return qvals0(stacked, obs, loc)
+
+    envs = [LandmarkEnv(vol, lm, cfg) for _ in fleet]
+    cropped = [0]
+    for env in envs:
+        env.observe = _counting(env.observe, cropped)
+    qvals0, steps.qvals = steps.qvals, qvals
+    try:
+        collect_fleet(fleet, envs, erbs_f, 4)
+    finally:
+        steps.qvals = qvals0
+    (span,) = tel.tracer.spans("fleet.collect")
+    assert span["args"]["crop_rows"] == cropped[0]
+    for a, erb in zip(legacy, erbs_l):
+        a.collect(LandmarkEnv(vol, lm, cfg), erb, 4)
+    # rows finished mid-episode, one agent before the others, and the
+    # cohort before the step budget (an ERB's version counts its appends)
+    assert any(e.size < 4 * e.version for e in erbs_f)
+    assert min(e.version for e in erbs_f) < len(staged) < cfg.max_episode_steps
+    for ef, el, fa, la in zip(erbs_f, erbs_l, fleet, legacy):
+        assert ef.size == el.size > 0
+        np.testing.assert_array_equal(erb_flatten(ef), erb_flatten(el))
+        assert fa.rng.bit_generator.state == la.rng.bit_generator.state
+    env = LandmarkEnv(vol, lm, cfg)
+    for obs, loc in staged:
+        for i in range(len(fleet)):
+            locs = np.rint(loc[i] * (env.n - 1)).astype(np.int32)
+            np.testing.assert_array_equal(obs[i], env.observe(locs))
+        assert not obs[len(fleet) :].any()  # the padding lane stays zero
 
 
 # -- pow2 slot bucketing -----------------------------------------------------
@@ -396,18 +487,25 @@ def test_collect_span_counts_env_steps_and_uploads():
     agents = [DQNAgent(i, cfg, seed=i, engine=engine) for i in range(3)]
     task = TaskTag("t1", "axial", "HGG")
     vol, lm = make_volume(task, 2, n=16)
-    appended = []
+    appended, cropped = [], []
     for episodes in (6, 4):
         erbs = [erb_init(256, cfg.box_size, task=task) for _ in agents]
         envs = [LandmarkEnv(vol, lm, cfg) for _ in agents]
+        rows = [0]
+        for env in envs:
+            env.observe = _counting(env.observe, rows)
         collect_fleet(agents, envs, erbs, episodes)
         appended.append(sum(e.size for e in erbs))
+        cropped.append(rows[0])
     spans = tel.tracer.spans("fleet.collect")
     assert len(spans) == 2
-    for span, rows, episodes in zip(spans, appended, (6, 4)):
+    for span, rows, crops, episodes in zip(spans, appended, cropped, (6, 4)):
         a = span["args"]
         assert a["agents"] == 3 and a["env_steps"] == rows > 0
         assert 1 <= a["dispatches"] <= cfg.max_episode_steps
+        # each agent crops its start, then at most one batch per dispatch
+        assert a["crop_rows"] == crops <= 3 * episodes * (a["dispatches"] + 1)
+        assert a["crop_rows"] < 2 * a["env_steps"]
         # a 3-agent cohort runs as a 4-lane bucket
         staged = 4 * episodes * (np.prod(cfg.box_size) + 3) * 4
         assert a["h2d_bytes"] == a["dispatches"] * staged
