@@ -4,9 +4,9 @@ runner builds it.
 
 The mix file gives the deployment (agents, hubs, speeds, tasks, train
 steps per round, episodes collected per round); the configuration file
-gives the agent's sizes and the scale (ERB capacity and share size, rounds
-per job, patients). Job ``j`` of a run seeded ``s`` is a federation seeded
-from ``(s, j)``.
+gives the agent's network and sizes and the scale (ERB capacity and share
+size, rounds per job, patients). Job ``j`` of a run seeded ``s`` is a
+federation seeded from ``(s, j)``.
 
 What ``correct`` compares (``check``): the window's first flush, one
 scan-fused chunk of K steps over every job it holds, is captured at the
@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import compare
-from harness.program import dqn_config
+from harness.program import REQUIRED_KEYS, dqn_config
 
 FLAT_FIELDS = ("obs", "loc", "action", "reward", "next_obs", "next_loc", "done")
 CONTROL = "fp8"  # the reference mode that is the control (references/dqn.py)
@@ -69,6 +69,37 @@ def chunk_buckets(cfg: dict, mix: dict) -> list[tuple[int, int]]:
     return out
 
 
+def run_recorded(system, rounds: int) -> list[list[list[tuple[str, int]]]]:
+    """Run a federation job to its end; each flush as its jobs, each job
+    as the (id, size) of the buffers it draws from, in order."""
+    engine = system.engine
+    flushes = []
+
+    def record(jobs, run=engine._flush_group):
+        flushes.append([[(e.meta.erb_id, e.size) for e in job.erbs] for job in jobs])
+        run(jobs)
+
+    engine._flush_group = record
+    system.sched.run(stop=lambda: all(a.rounds_done >= rounds for a in system.agents.values()))
+    engine.flush()
+    return flushes
+
+
+def pool_sequences(flushes) -> set[tuple[int, ...]]:
+    """The buffer sizes, in order, of each pool these flushes build, and of
+    the pool of each flush's leading jobs (a deadline flushes those)."""
+    out = set()
+    for jobs in flushes:
+        for n in range(1, len(jobs) + 1):
+            seen, sizes = set(), []
+            for erb_id, size in (e for job in jobs[:n] for e in job):
+                if erb_id not in seen:
+                    seen.add(erb_id)
+                    sizes.append(size)
+            out.add(tuple(sizes))
+    return out
+
+
 class Capture:
     """What the window's first chunk and collection calls saw and gave."""
 
@@ -79,8 +110,6 @@ class Capture:
 
 class Session:
     def __init__(self, cfg: dict, mix: dict, seed: int, ref, telemetry=None, fault: str | None = None):
-        from repro.core.erb import flat_width
-        from repro.kernels.replay_gather.ops import pool_width
         from repro.rl.fleet import make_collect_steps, make_fleet_steps
         from repro.rl.synth import make_volume, paper_eight_tasks, patient_split
 
@@ -100,7 +129,6 @@ class Session:
                 make_volume(task, p, n=side)
         self.steps = make_fleet_steps(self.dqn)
         self.collect = make_collect_steps(self.dqn)
-        self.width = pool_width(flat_width(self.dqn.box_size))
         self.capture = Capture()
         self._copy = jax.jit(
             lambda t: jax.tree_util.tree_map(lambda x: x + jnp.zeros((), x.dtype), t)
@@ -122,9 +150,12 @@ class Session:
     def _warm(self) -> None:
         """Compile (or load) the programs of this cell's shapes only: the
         chunk at every reachable (K, N, R) bucket, the collection step,
-        and the capture's copy."""
+        and the capture's copy. The pool's row width is the one the
+        federation's engine computes for this configuration."""
         k, b = self.mix["train_steps_per_round"], self.dqn.batch_size
         f32 = jnp.float32
+        system = self._system(job_seed(self.seed, 0))
+        self.width = system.engine._feat
         self.buckets = chunk_buckets(self.cfg, self.mix)
         for n, r in self.buckets:
             state = self._state_shape(n)
@@ -140,17 +171,17 @@ class Session:
             jax.ShapeDtypeStruct((1, e, *box), f32),
             jax.ShapeDtypeStruct((1, e, 3), f32),
         ).compile()
-        self._warm_flushes()
+        self._warm_flushes(system)
+        del system  # its engine's state and buffer cache go before the pools are built
         self._warm_pools()
 
-    def _warm_flushes(self) -> None:
+    def _warm_flushes(self, system) -> None:
         """Drive one federation's engine through a flush of 1, 2, 3 and 4
         jobs, each job over its own full buffer of zeros: the per-leaf
         gathers and scatters of every jobs bucket and the collection's
         parameter gather run once here, not in the window."""
         from repro.core.erb import erb_add, erb_init
 
-        system = self._system(job_seed(self.seed, 0))
         engine, agents = system.engine, list(system.agents.values())
         cap, box, b = self.cfg["erb_capacity"], self.dqn.box_size, self.dqn.batch_size
         zeros = {
@@ -179,38 +210,22 @@ class Session:
         its jobs, each new sequence of buffer sizes a program of its own,
         and pads them with zeros to a power of two of rows. A twin of each
         job (its seed, so the same schedule and buffers; crop and network
-        shrunk) runs and records the buffers of each flush; a deadline that stops the window mid-job flushes the
-        leading jobs of the flush that was due. The twin's episodes are as
-        short as still fill every buffer to its cap, and it trains one step
-        a round: neither moves the schedule, which draws only round
-        durations, tasks and patients from the federation's seed."""
-        cap, episodes = self.cfg["erb_capacity"], self.mix["collect_episodes"]
+        shrunk) runs and records the buffers of each flush; a deadline that
+        stops the window mid-job flushes the leading jobs of the flush that
+        was due. The twin collects and trains as many steps as the window
+        does: each agent's random stream, which decides which buffers a
+        job draws rows from and in what order, then runs as in the window
+        (with one train step a round, or shorter episodes, some sequences
+        differ and compile in the window). The twin is built from the
+        ``REQUIRED_KEYS`` alone: no other network key of the configuration
+        reaches it, since the network moves no buffer size."""
         twin = dqn_config(dict(
-            self.cfg, box_size=[5, 5, 5], conv_features=[4], hidden=[8],
-            max_episode_steps=min(self.cfg["max_episode_steps"], -(-cap // episodes) + 2),
+            {k: self.cfg[k] for k in REQUIRED_KEYS}, box_size=[5, 5, 5], conv_features=[4], hidden=[8]
         ))
-        flushes: list[list[list[tuple[str, int]]]] = []
+        flushes = []
         for j in range(self.mix["warm_jobs"]):
-            system = self._system(job_seed(self.seed, j), twin, telemetry=False, train_steps=1)
-            engine = system.engine
-
-            def record(jobs, run=engine._flush_group):
-                flushes.append([[(e.meta.erb_id, e.size) for e in job.erbs] for job in jobs])
-                run(jobs)
-
-            engine._flush_group = record
-            system.sched.run(stop=lambda s=system: all(
-                a.rounds_done >= self.cfg["rounds"] for a in s.agents.values()))
-            engine.flush()
-        sequences = set()
-        for jobs in flushes:
-            for n in range(1, len(jobs) + 1):
-                seen, sizes = set(), []
-                for erb_id, size in (e for job in jobs[:n] for e in job):
-                    if erb_id not in seen:
-                        seen.add(erb_id)
-                        sizes.append(size)
-                sequences.add(tuple(sizes))
+            flushes += run_recorded(self._system(job_seed(self.seed, j), twin, telemetry=False), self.cfg["rounds"])
+        self.pool_sequences = sequences = pool_sequences(flushes)
         blocks = {n: jnp.zeros((n, 1, self.width), jnp.float32) for n in {n for s in sequences for n in s}}
         for sizes in sorted(sequences):
             parts = [blocks[n] for n in sizes]
@@ -221,7 +236,7 @@ class Session:
             if len(parts) > 1:
                 jax.block_until_ready(jnp.concatenate(parts, axis=0))
 
-    def _system(self, seed: int, dqn=None, telemetry: bool = True, train_steps: int | None = None):
+    def _system(self, seed: int, dqn=None, telemetry: bool = True):
         from repro.configs.adfll_dqn import ADFLLConfig
         from repro.core.federated import ADFLLSystem
 
@@ -235,7 +250,7 @@ class Session:
             rounds=cfg["rounds"],
             erb_capacity=cfg["erb_capacity"],
             erb_share_size=cfg["erb_share_size"],
-            train_steps_per_round=train_steps or mix["train_steps_per_round"],
+            train_steps_per_round=mix["train_steps_per_round"],
             topology=mix["topology"],
             share_planes=tuple(mix["share_planes"]),
             seed=seed,

@@ -1,33 +1,27 @@
-"""Operations and bytes of the DQN agent's programs, from shapes alone.
+"""Operations and bytes of the agent's programs, from shapes alone.
 
-Dense counts: a stride-2, kernel-3, SAME convolution costs
-``out_voxels * 27 * c_in * c_out`` multiply-adds, taps that land on the
-zero padding included (XLA's cost analysis skips those, so it reads
-lower); a dense layer ``d_in * d_out``. One multiply-add is two FLOPs.
+The counts that depend on the network and the replay row's layout are
+the configuration's reference's own (``references/<reference>.py``:
+``macs_per_sample(cfg)``, ``row_floats(cfg)``); this module turns them
+into a training step's FLOPs and the device pool's bytes. One
+multiply-add is two FLOPs.
 """
 
 from __future__ import annotations
 
-import numpy as np
+from . import loading
 
 LANES = 128  # the device pool's row width is rounded up to whole lanes
 
 
 def macs_per_sample(cfg: dict) -> int:
-    """Multiply-adds of one forward pass over one crop."""
-    dims = list(cfg["box_size"])
-    cin = 1
-    total = 0
-    for cout in cfg["conv_features"]:
-        dims = [-(-d // 2) for d in dims]
-        total += int(np.prod(dims)) * 27 * cin * cout
-        cin = cout
-    d = int(np.prod(dims)) * cin + 16
-    total += 3 * 16
-    for h in list(cfg["hidden"]) + [cfg["n_actions"]]:
-        total += d * h
-        d = h
-    return total
+    """Multiply-adds of one forward pass over one observation."""
+    return loading.reference(cfg["reference"]).macs_per_sample(cfg)
+
+
+def row_floats(cfg: dict) -> int:
+    """Floats of one replay row."""
+    return loading.reference(cfg["reference"]).row_floats(cfg)
 
 
 def train_flops_per_agent_step(cfg: dict) -> int:
@@ -35,12 +29,6 @@ def train_flops_per_agent_step(cfg: dict) -> int:
     (three forward passes' worth) plus the target network's forward, over
     the minibatch."""
     return 2 * macs_per_sample(cfg) * cfg["batch_size"] * 4
-
-
-def row_floats(cfg: dict) -> int:
-    """Floats of one replay row: obs and next obs, loc and next loc,
-    action, reward, done."""
-    return 2 * int(np.prod(cfg["box_size"])) + 9
 
 
 def pool_row_bytes(cfg: dict) -> int:

@@ -6,7 +6,20 @@ in a file of its own under the benchmark's directory, so a later change
 adds a cell or a metric by adding files:
 
 * ``configs/<config>.json``  — sizes, source, ``reduced``, ``assumed``;
-  ``"reference"`` names the plain reference in ``references/``.
+  ``"reference"`` names the plain reference in ``references/``. The
+  ``REQUIRED_KEYS`` of ``harness/program.py`` sit at the top level; an
+  ``"agent"`` block gives the network's other ``DQNConfig`` fields, and a
+  key there that the program lacks stops set-up.
+* ``references/<reference>.py`` — the plain reference of one network,
+  importing nothing of the program. It defines ``MODES`` (its precision
+  modes, the default and reference first), ``init_params(seed, cfg)``,
+  ``train(cfg, params, pool, idx, count0, mode)`` (one agent's steps over
+  flat pool rows: losses, the first gradient's per-leaf norms, each
+  leaf's change norm after each step), ``qvals(cfg, params, obs, loc,
+  mode)``, ``macs_per_sample(cfg)`` and ``row_floats(cfg)`` (the
+  counts ``harness/flops.py`` turns into FLOPs and pool bytes). A new
+  architecture is a new configuration, a new reference and a new limits
+  file.
 * ``traffic/<mix>.json``     — the mix's parameters; ``"driver"`` names
   the general generator in ``drivers/`` that reads them.
 * ``limits/<cell>.json``     — the limit of each number compared for
@@ -18,10 +31,12 @@ from __future__ import annotations
 
 import importlib.util
 import json
+from functools import lru_cache
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 CHECKOUT = BENCH.parent
+REFERENCES = BENCH / "references"
 
 
 def load_json(path: Path) -> dict:
@@ -36,6 +51,16 @@ def load_module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@lru_cache(maxsize=None)
+def _load_reference(path: Path):
+    return load_module(path, f"bench_ref_{path.stem}")
+
+
+def reference(name: str):
+    """The plain reference ``references/<name>.py``, loaded once."""
+    return _load_reference(REFERENCES / f"{name}.py")
 
 
 class Catalog:
@@ -65,7 +90,7 @@ class Catalog:
         return load_module(BENCH / "drivers" / f"{name}.py", f"bench_driver_{name}")
 
     def reference(self, name: str):
-        return load_module(BENCH / "references" / f"{name}.py", f"bench_ref_{name}")
+        return reference(name)
 
     def end_to_end(self, cell: str) -> list[dict]:
         return [

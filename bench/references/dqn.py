@@ -20,6 +20,9 @@ bfloat16 (the storage step below float32), for the readings only.
 
 The replay rows are the flat layout the benchmark reads from the device
 pool: obs, loc, action, reward, next_obs, next_loc, done, then zero lanes.
+``macs_per_sample`` and ``row_floats`` count this network's operations
+and this row's floats; the benchmark's FLOP and byte counters
+(``harness/flops.py``) take them from here.
 """
 
 from __future__ import annotations
@@ -119,22 +122,45 @@ def forward(cfg: dict, p, obs, loc, mode: str = "highest"):
     return x
 
 
+def row_fields(cfg: dict) -> list[tuple[str, int]]:
+    """The flat replay row's fields and their widths, in order."""
+    f = int(np.prod(cfg["box_size"]))
+    return [("obs", f), ("loc", 3), ("action", 1), ("reward", 1), ("next_obs", f), ("next_loc", 3), ("done", 1)]
+
+
+def row_floats(cfg: dict) -> int:
+    """Floats of one replay row: obs and next obs, loc and next loc,
+    action, reward, done."""
+    return sum(width for _, width in row_fields(cfg))
+
+
+def macs_per_sample(cfg: dict) -> int:
+    """Multiply-adds of one forward pass over one crop, by the dense count:
+    a convolution costs ``out_voxels * 27 * c_in * c_out``, taps that land
+    on the zero padding included (XLA's cost analysis skips those, so it
+    reads lower); the location branch ``3 * 16``; a dense layer
+    ``d_in * d_out``."""
+    dims = list(cfg["box_size"])
+    cin = 1
+    total = 3 * 16
+    for cout in cfg["conv_features"]:
+        dims = conv_dims(dims, 1)
+        total += int(np.prod(dims)) * 27 * cin * cout
+        cin = cout
+    d = int(np.prod(dims)) * cin + 16
+    for h in list(cfg["hidden"]) + [cfg["n_actions"]]:
+        total += d * h
+        d = h
+    return total
+
+
 def split_rows(cfg: dict, rows):
     """[B, W] flat replay rows -> batch dict."""
     box = tuple(cfg["box_size"])
-    f = int(np.prod(box))
     b = rows.shape[0]
     o = 0
     out = {}
-    for key, width in (
-        ("obs", f),
-        ("loc", 3),
-        ("action", 1),
-        ("reward", 1),
-        ("next_obs", f),
-        ("next_loc", 3),
-        ("done", 1),
-    ):
+    for key, width in row_fields(cfg):
         out[key] = rows[:, o : o + width]
         o += width
     out["obs"] = out["obs"].reshape(b, *box)

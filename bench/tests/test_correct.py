@@ -32,11 +32,13 @@ def one(run, cat, cell, fault=None, trace=False):
     return run.run_cell(cat, cell, SEED, 2.0, trace, jax.devices(), fault=fault, log=lambda *_: None)
 
 
-CELL = "tiny.fed_small"
+# two configurations of one traffic mix through the same driver
+CELLS = ["tiny.fed_small", "tiny_deep.fed_small"]
 
 
-def test_sound_run_is_correct(run, cat):
-    out = one(run, cat, CELL)
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct(run, cat, cell):
+    out = one(run, cat, cell)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
     assert out["attempted"] > 0 and out["failed"] == 0
@@ -52,13 +54,15 @@ def test_sound_run_is_correct(run, cat):
         "control",  # the reference in float8 in the program's place
     ],
 )
-def test_planted_fault_is_not_correct(run, cat, fault):
-    out = one(run, cat, CELL, fault=fault)
+@pytest.mark.parametrize("cell", CELLS)
+def test_planted_fault_is_not_correct(run, cat, cell, fault):
+    out = one(run, cat, cell, fault=fault)
     assert not out["correct"], out["checks"]
 
 
-def test_traced_run_reports_layers(run, cat):
-    out = one(run, cat, CELL, trace=True)
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_run_reports_layers(run, cat, cell):
+    out = one(run, cat, cell, trace=True)
     assert out["correct"]
     assert {"fed.host_s_per_round", "flush.ms_per_step", "compiles.train"} <= set(out["metrics"])
     assert out["device"]["window_s"] > 0
