@@ -29,12 +29,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import compare
+from harness import compare, flops
 from harness.program import REQUIRED_KEYS, dqn_config
 
-FLAT_FIELDS = ("obs", "loc", "action", "reward", "next_obs", "next_loc", "done")
 CONTROL = "fp8"  # the reference mode that is the control (references/dqn.py)
-GROWTH_STEPS = (1, 3, 10, 30, 75, 150)
+# steps at which a stand-in's gaps are read: the first steps, either side
+# of the target syncs at 50 and 100, and the chunk's end
+GROWTH_STEPS = (1, 2, 3, 5, 10, 20, 30, 49, 50, 51, 75, 100, 101, 150)
 
 
 def pow2(n: int) -> int:
@@ -113,10 +114,11 @@ class Session:
         from repro.rl.fleet import make_collect_steps, make_fleet_steps
         from repro.rl.synth import make_volume, paper_eight_tasks, patient_split
 
-        self.cfg, self.mix, self.seed = cfg, mix, seed
+        self.cfg, self.mix, self.seed, self.ref = cfg, mix, seed, ref
         self.telemetry = telemetry
         self.fault = fault
         self.dqn = dqn_config(cfg)
+        self.obs = tuple(ref.obs_shape(cfg))  # one observation as the network reads it
         if mix["task_set"] != "paper8":
             raise ValueError(f"unknown task set {mix['task_set']!r}")
         self.tasks = list(paper_eight_tasks())
@@ -151,11 +153,34 @@ class Session:
         """Compile (or load) the programs of this cell's shapes only: the
         chunk at every reachable (K, N, R) bucket, the collection step,
         and the capture's copy. The pool's row width is the one the
-        federation's engine computes for this configuration."""
+        federation's engine computes for this configuration, and has to be
+        the reference's row lane-padded; the observation is the
+        reference's ``obs_shape``, and the collection step has to take it.
+        Either disagreement stops set-up with a ``ValueError``."""
         k, b = self.mix["train_steps_per_round"], self.dqn.batch_size
         f32 = jnp.float32
+        name, obs = self.cfg.get("name", "?"), self.obs
         system = self._system(job_seed(self.seed, 0))
         self.width = system.engine._feat
+        floats = self.ref.row_floats(self.cfg)
+        if self.width != flops.lane_pad(floats):
+            raise ValueError(
+                f"configuration {name!r}: the program's pool row is {self.width} floats wide, "
+                f"its reference's row of {floats} floats lane-padded is {flops.lane_pad(floats)}"
+            )
+        e = self.mix["collect_episodes"]
+        try:
+            collect = self.collect.qvals.lower(
+                self._state_shape(1).params,
+                jax.ShapeDtypeStruct((1, e, *obs), f32),
+                jax.ShapeDtypeStruct((1, e, 3), f32),
+            )
+        except TypeError as err:
+            raise ValueError(
+                f"configuration {name!r}: the program's collection step takes no observation of "
+                f"its reference's obs_shape {obs}: {err}"
+            ) from err
+        collect.compile()
         self.buckets = chunk_buckets(self.cfg, self.mix)
         for n, r in self.buckets:
             state = self._state_shape(n)
@@ -164,13 +189,6 @@ class Session:
             self.steps.train_chunk.lower(state, pool, idx).compile()
         for n in sorted({n for n, _ in self.buckets}):
             self._copy.lower(self._state_shape(n)).compile()
-        e, box = self.mix["collect_episodes"], self.dqn.box_size
-        params = self._state_shape(1).params
-        self.collect.qvals.lower(
-            params,
-            jax.ShapeDtypeStruct((1, e, *box), f32),
-            jax.ShapeDtypeStruct((1, e, 3), f32),
-        ).compile()
         self._warm_flushes(system)
         del system  # its engine's state and buffer cache go before the pools are built
         self._warm_pools()
@@ -183,22 +201,15 @@ class Session:
         from repro.core.erb import erb_add, erb_init
 
         engine, agents = system.engine, list(system.agents.values())
-        cap, box, b = self.cfg["erb_capacity"], self.dqn.box_size, self.dqn.batch_size
-        zeros = {
-            "obs": np.zeros((cap, *box), np.float32),
-            "loc": np.zeros((cap, 3), np.float32),
-            "action": np.zeros((cap,), np.int32),
-            "reward": np.zeros((cap,), np.float32),
-            "next_obs": np.zeros((cap, *box), np.float32),
-            "next_loc": np.zeros((cap, 3), np.float32),
-            "done": np.zeros((cap,), np.float32),
-        }
+        cap, b = self.cfg["erb_capacity"], self.dqn.batch_size
+        task = self.tasks[0]
+        zeros = {k: np.zeros_like(v) for k, v in erb_init(cap, self.obs, task=task).data.items()}
         rng = np.random.default_rng(0)
         # 1, 2, 3 and 4 jobs in slot order, then 4 in another order (a
         # flush of every slot that is not the resident fast path)
         for order in [agents[:n] for n in range(1, len(agents) + 1)] + [agents[::-1]]:
             for a in order:
-                erb = erb_add(erb_init(cap, box, task=self.tasks[0]), zeros)
+                erb = erb_add(erb_init(cap, self.obs, task=task), zeros)
                 plans = [a.sampler.plan(rng, b, erb) for _ in range(self.mix["train_steps_per_round"])]
                 engine.submit(a.slot, plans)
             engine.flush()
@@ -408,12 +419,19 @@ def _reference(memo: dict, key, fn):
 
 
 def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None = None,
-          memo: dict | None = None) -> dict:
+          memo: dict | None = None, detail: bool = False) -> dict:
     """The compared numbers. With ``stand_in`` (a mode of the reference,
     ``CONTROL`` for the control) the reference computed in that mode
     stands in the program's place, and the numbers also give how its
     median-leaf and worst-leaf gaps grow with the steps taken
     (``dparam_gap_by_k``, ``dparam_worst_by_k``).
+    With ``detail`` (the readings tool) the numbers also hold, under
+    ``detail``, readings that are not compared: each kept leaf's gap after
+    the chunk and its size and first-gradient norm over the median leaf's
+    (``leaves``), the gap of the change's norm over all kept leaves
+    together (``global``, signed, per lane), and for a stand-in each leaf's
+    gap at each step (``leaf_by_k``) and the worst and median leaf's gap of
+    the first gradient's norms (``grad1_worst``, ``grad1_gap``).
     ``memo`` keeps the reference's own results between calls on one
     capture."""
     memo = {} if memo is None else memo
@@ -422,6 +440,7 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
         jax.tree_util.tree_map(np.asarray, ref.init_params(seed + a, cfg)) for a in range(mix["n_agents"])
     ])
     names = compare.leaf_names(inits[0])
+    sizes = [x.size for x in compare.leaves(inits[0])]
     idx, losses = cap["idx"], cap["losses"]
     real = [i for i in range(idx.shape[1]) if idx[:, i, :].any()]
     pool = jnp.asarray(cap["pool"])
@@ -429,6 +448,7 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
            "dparam_leaf": "", "init_gap": 0.0}
     steps = [k for k in GROWTH_STEPS if k <= idx.shape[0]]
     growth = np.zeros((2, len(steps)))  # median leaf, worst leaf
+    more = {"leaves": {}, "global": [], "leaf_by_k": {}, "grad1_worst": 0.0, "grad1_gap": 0.0} if detail else None
     for lane in real:
         prog_in = _lane(cap["state_in"], lane)
         gaps = [_init_gap(prog_in, p) for p in inits]
@@ -443,11 +463,19 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
             prog_loss = losses[:, lane]
             prog_moved = _change_norms(_lane(cap["state_out"], lane), prog_in)
         else:
-            prog_loss, _, si_moved = ref.train(cfg, start, pool, idx[:, lane, :], count0, stand_in)
+            prog_loss, si_g1, si_moved = ref.train(cfg, start, pool, idx[:, lane, :], count0, stand_in)
             prog_moved = si_moved[-1]
             for j, k in enumerate(steps):
-                g, _ = compare.change_norm_gaps(si_moved[k - 1], ref_moved[k - 1], ref_g1)
+                g, kept = compare.change_norm_gaps(si_moved[k - 1], ref_moved[k - 1], ref_g1)
                 growth[:, j] = np.maximum(growth[:, j], [np.median(g), np.max(g)])
+                if more:
+                    for i, v in zip(kept, g):
+                        row = more["leaf_by_k"].setdefault(names[i], [0.0] * len(steps))
+                        row[j] = max(row[j], float(v))
+            if more:
+                g, _ = compare.change_norm_gaps(si_g1, ref_g1, ref_g1)
+                more["grad1_worst"] = max(more["grad1_worst"], float(np.max(g)))
+                more["grad1_gap"] = max(more["grad1_gap"], float(np.median(g)))
         rel = np.abs(np.asarray(prog_loss[:3], np.float64) - ref_loss[:3]) / np.abs(ref_loss[:3])
         out["loss1_gap"] = max(out["loss1_gap"], float(rel[0]))
         out["loss_gap"] = max(out["loss_gap"], float(rel.max()))
@@ -457,9 +485,20 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
         if leaf_gaps[worst] >= out["dparam_worst"]:
             out["dparam_worst"] = float(leaf_gaps[worst])
             out["dparam_leaf"] = names[keep[worst]]
+        if more:
+            g1_median = float(np.median(ref_g1[keep]))
+            for i, v in zip(keep, leaf_gaps):
+                was = more["leaves"].get(names[i], [0.0, sizes[i], np.inf])
+                more["leaves"][names[i]] = [max(was[0], float(v)), sizes[i], min(was[2], float(ref_g1[i]) / g1_median)]
+            dp, dr = (np.sqrt(np.sum(np.asarray(x, np.float64)[keep] ** 2)) for x in (prog_moved, ref_moved[-1]))
+            more["global"].append(float((dp - dr) / dr))
     if stand_in is not None:
         out["dparam_gap_by_k"] = dict(zip(map(str, steps), growth[0].tolist()))
         out["dparam_worst_by_k"] = dict(zip(map(str, steps), growth[1].tolist()))
+    if more:
+        if stand_in is None:
+            del more["leaf_by_k"], more["grad1_worst"], more["grad1_gap"]
+        out["detail"] = dict(more, steps=steps)
     # the collection step: the lane's own agent, the program's crops
     q_in = _lane(cap["q_params"], 0)
     gaps = [_init_gap(q_in, p) for p in inits]
@@ -470,10 +509,12 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
     out["collect_q_gap"] = compare.rel_gap(q_prog, q_ref)
 
     # every row the chunk read is a row of the federation's buffers
+    fields = ref.row_fields(cfg)
+
     def unmatched():
         known = set()
         for erb in cap["job0"]["erbs"]:
-            known |= compare.row_digests(flat_rows(erb, cap["pool"].shape[1]))
+            known |= compare.row_digests(flat_rows(erb, fields, cap["pool"].shape[1]))
         used = np.unique(idx[:, real, :])
         return float(len(compare.row_digests(cap["pool"][used]) - known))
 
@@ -482,14 +523,18 @@ def check(cfg: dict, mix: dict, cap: dict, ref, seed: int, stand_in: str | None 
     return out
 
 
-def flat_rows(erb, width: int) -> np.ndarray:
+def flat_rows(erb, fields, width: int) -> np.ndarray:
     """An experience buffer's filled rows in the pool's flat layout:
-    obs, loc, action, reward, next obs, next loc, done, zero lanes."""
+    ``fields`` (the reference's ``row_fields``: name and flat width, in
+    order), then zero lanes to ``width``. A field whose buffer is not of
+    that width raises a ``ValueError``."""
     s = erb.size
-    cols = [np.asarray(erb.data[k][:s], np.float32).reshape(s, -1) for k in FLAT_FIELDS]
     out = np.zeros((s, width), np.float32)
     o = 0
-    for c in cols:
-        out[:, o : o + c.shape[1]] = c
-        o += c.shape[1]
+    for key, w in fields:
+        col = np.asarray(erb.data[key][:s], np.float32).reshape(s, -1)
+        if col.shape[1] != w:
+            raise ValueError(f"buffer field {key!r} is {col.shape[1]} floats wide, the row lays out {w}")
+        out[:, o : o + w] = col
+        o += w
     return out

@@ -31,9 +31,15 @@ def train_flops_per_agent_step(cfg: dict) -> int:
     return 2 * macs_per_sample(cfg) * cfg["batch_size"] * 4
 
 
+def lane_pad(floats: int) -> int:
+    """A row of ``floats`` floats as the device pool holds it: rounded up
+    to whole lanes."""
+    return -(-floats // LANES) * LANES
+
+
 def pool_row_bytes(cfg: dict) -> int:
     """Bytes of one float32 row of the device pool (lane-padded)."""
-    return -(-row_floats(cfg) // LANES) * LANES * 4
+    return lane_pad(row_floats(cfg)) * 4
 
 
 def gather_bytes(cfg: dict, rows: int) -> int:

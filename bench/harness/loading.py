@@ -16,10 +16,16 @@ adds a cell or a metric by adding files:
   ``train(cfg, params, pool, idx, count0, mode)`` (one agent's steps over
   flat pool rows: losses, the first gradient's per-leaf norms, each
   leaf's change norm after each step), ``qvals(cfg, params, obs, loc,
-  mode)``, ``macs_per_sample(cfg)`` and ``row_floats(cfg)`` (the
-  counts ``harness/flops.py`` turns into FLOPs and pool bytes). A new
-  architecture is a new configuration, a new reference and a new limits
-  file.
+  mode)``, ``obs_shape(cfg)`` (the shape of one observation as the
+  network reads it: the driver lowers the collection step and fills its
+  warm buffers at this shape), ``row_fields(cfg)`` (the replay row's
+  fields and their flat widths, in the order the pool lays them out: the
+  driver reads the pool's rows back by it), ``macs_per_sample(cfg)`` and
+  ``row_floats(cfg)`` (the counts ``harness/flops.py`` turns into FLOPs
+  and pool bytes). Set-up stops where the program's pool row is not
+  ``row_floats`` lane-padded, or its collection step takes no
+  ``obs_shape`` observation. A new architecture is a new configuration, a
+  new reference and a new limits file.
 * ``traffic/<mix>.json``     — the mix's parameters; ``"driver"`` names
   the general generator in ``drivers/`` that reads them.
 * ``limits/<cell>.json``     — the limit of each number compared for

@@ -18,11 +18,13 @@ operands rounded to float8 (e4m3, one scale per tensor), float32
 accumulation, gradients and storage. ``bf16`` keeps every array and operation in
 bfloat16 (the storage step below float32), for the readings only.
 
-The replay rows are the flat layout the benchmark reads from the device
-pool: obs, loc, action, reward, next_obs, next_loc, done, then zero lanes.
-``macs_per_sample`` and ``row_floats`` count this network's operations
-and this row's floats; the benchmark's FLOP and byte counters
-(``harness/flops.py``) take them from here.
+The network reads one crop (``obs_shape``). The replay rows are the flat
+layout the benchmark reads from the device pool (``row_fields``): obs,
+loc, action, reward, next_obs, next_loc, done, then zero lanes. The
+benchmark's driver shapes its warm-up and reads the pool's rows by these
+two; ``macs_per_sample`` and ``row_floats`` count this network's
+operations and this row's floats for its FLOP and byte counters
+(``harness/flops.py``).
 """
 
 from __future__ import annotations
@@ -65,7 +67,7 @@ def init_params(seed: int, cfg: dict):
             "b": jnp.zeros((cout,), jnp.float32),
         }
         cin = cout
-    dims = conv_dims(cfg["box_size"], len(cfg["conv_features"]))
+    dims = conv_dims(obs_shape(cfg), len(cfg["conv_features"]))
     d = dims[0] * dims[1] * dims[2] * cin + 16
     p["loc"] = {"w": tn(ks[5], (3, 16), 3**-0.5), "b": jnp.zeros((16,), jnp.float32)}
     for i, h in enumerate(list(cfg["hidden"]) + [cfg["n_actions"]]):
@@ -122,9 +124,14 @@ def forward(cfg: dict, p, obs, loc, mode: str = "highest"):
     return x
 
 
+def obs_shape(cfg: dict) -> tuple[int, ...]:
+    """The shape of one observation as the network reads it: one crop."""
+    return tuple(cfg["box_size"])
+
+
 def row_fields(cfg: dict) -> list[tuple[str, int]]:
     """The flat replay row's fields and their widths, in order."""
-    f = int(np.prod(cfg["box_size"]))
+    f = int(np.prod(obs_shape(cfg)))
     return [("obs", f), ("loc", 3), ("action", 1), ("reward", 1), ("next_obs", f), ("next_loc", 3), ("done", 1)]
 
 
@@ -140,7 +147,7 @@ def macs_per_sample(cfg: dict) -> int:
     on the zero padding included (XLA's cost analysis skips those, so it
     reads lower); the location branch ``3 * 16``; a dense layer
     ``d_in * d_out``."""
-    dims = list(cfg["box_size"])
+    dims = list(obs_shape(cfg))
     cin = 1
     total = 3 * 16
     for cout in cfg["conv_features"]:
@@ -156,7 +163,7 @@ def macs_per_sample(cfg: dict) -> int:
 
 def split_rows(cfg: dict, rows):
     """[B, W] flat replay rows -> batch dict."""
-    box = tuple(cfg["box_size"])
+    box = obs_shape(cfg)
     b = rows.shape[0]
     o = 0
     out = {}

@@ -41,23 +41,28 @@ def test_sound_run_is_correct(run, cat, cell):
     out = one(run, cat, cell)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
+    assert out["checks"]["rows_unmatched"]["value"] == 0  # rows read back by the reference's row_fields
     assert out["attempted"] > 0 and out["failed"] == 0
     assert "setup_s" in out["metrics"]
 
 
 @pytest.mark.parametrize(
-    "fault",
+    "fault, fails",
     [
-        "unchanged",  # a step returns its state unchanged
-        "half_batch",  # half the batch left out, the mean over the rest
-        "altered",  # a collection answer altered where it is produced
-        "control",  # the reference in float8 in the program's place
+        ("unchanged", ["dparam_gap"]),  # a step returns its state unchanged
+        ("half_batch", ["loss1_gap"]),  # half the batch left out, the mean over the rest
+        ("altered", ["collect_q_gap"]),  # a collection answer altered where it is produced
+        ("control", ["loss1_gap", "collect_q_gap"]),  # the reference in float8 in the program's place
     ],
 )
 @pytest.mark.parametrize("cell", CELLS)
-def test_planted_fault_is_not_correct(run, cat, cell, fault):
+def test_planted_fault_is_not_correct(run, cat, cell, fault, fails):
+    """Each fault, and the control, is not correct, and fails at least the
+    numbers named for it."""
     out = one(run, cat, cell, fault=fault)
     assert not out["correct"], out["checks"]
+    for name in fails:
+        assert out["checks"][name]["value"] > out["checks"][name]["limit"], (name, out["checks"])
 
 
 @pytest.mark.parametrize("cell", CELLS)

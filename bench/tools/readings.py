@@ -78,7 +78,7 @@ def main(argv=None) -> int:
         cap = session.take_capture()
         tc = time.perf_counter()
         memo = {}  # the reference's own results on this capture
-        prog = driver.check(cfg, mix, cap, ref, seed, memo=memo)
+        prog = driver.check(cfg, mix, cap, ref, seed, memo=memo, detail=True)
         tp = time.perf_counter() - tc
         row = {"seed": seed, "fault": fault, "window_s": tc - tw, "check_s": tp,
                "correct": compare.judge(prog, limits)[0], "program": prog, "counts": res["counts"],
@@ -86,7 +86,7 @@ def main(argv=None) -> int:
         if fault is None:
             for mode in stand_ins:
                 tc = time.perf_counter()
-                numbers = driver.check(cfg, mix, cap, ref, seed, stand_in=mode, memo=memo)
+                numbers = driver.check(cfg, mix, cap, ref, seed, stand_in=mode, memo=memo, detail=True)
                 row[mode] = dict(numbers, correct=compare.judge(numbers, limits)[0],
                                  seconds=time.perf_counter() - tc)
         print(json.dumps(row), flush=True)
